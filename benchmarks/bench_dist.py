@@ -1,15 +1,17 @@
-"""Communication-efficient sharded conquer benchmark (ISSUE-6).
+"""Communication-efficient sharded conquer benchmark.
 
 Measures the parallel-block conquer (CE-PBM: every device solves its own
 top-B sub-QP per communication round) against the replicated single-block
-baseline at 1/2/4/8 forced host devices.  Devices must be fixed before jax
-initializes, so each device count runs in a worker subprocess
-(``python -m benchmarks.bench_dist --worker --devices P ...``) that prints a
-``DISTBENCH::{json}`` line; the parent collects the lines, asserts
+baseline over meshes of the first P devices, for every P in 1/2/4/8 that
+exists — all in ONE process, so a single process holds the chips.  On a
+host without accelerators, set
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before the run to
+rehearse on virtual CPU devices.  Asserts
 
   * both modes reach the dense single-device objective to 1e-3 relative, and
-  * at the largest device count the parallel conquer needs STRICTLY fewer
-    communication rounds to reach tol than the replicated baseline,
+  * at the largest device count (when > 1) the parallel conquer needs
+    STRICTLY fewer communication rounds to reach tol than the replicated
+    baseline,
 
 and writes BENCH_dist.json (rounds-to-tol + wall-clock per device count and
 mode, plus the bytes-per-round accounting from DESIGN.md §11).
@@ -18,44 +20,31 @@ mode, plus the bytes-per-round accounting from DESIGN.md §11).
 """
 from __future__ import annotations
 
-import argparse
-import json
-import os
-import subprocess
-import sys
+import dataclasses
 import time
 
+import jax
+import jax.numpy as jnp
+
+from benchmarks.common import emit, emit_json
+from repro.core import Kernel, gram
+from repro.core.distributed import ConquerConfig, conquer_step
+from repro.core.solver import solve_with_shrinking
+from repro.data import gaussian_mixture
+from repro.launch.mesh import make_conquer_mesh
+
 DEVICE_COUNTS = [1, 2, 4, 8]
+D_FEAT = 8
 
 
-def _worker(devices: int, n: int, block: int, tol: float,
-            max_iters: int) -> None:
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={devices}")
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import dataclasses
-
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core import Kernel, gram
-    from repro.core.distributed import ConquerConfig, conquer_step
-    from repro.core.solver import solve_with_shrinking
-    from repro.data import gaussian_mixture
-
-    assert jax.device_count() == devices, jax.device_count()
-    mesh = jax.make_mesh((devices,), ("i",))
-    kern = Kernel("rbf", gamma=8.0)
-    X, y = gaussian_mixture(jax.random.PRNGKey(0), n, d=8, modes_per_class=4)
-    Q = (y[:, None] * y[None, :]) * gram(kern, X, X)
-    ref = solve_with_shrinking(Q, 4.0, tol=tol / 10.0,
-                               max_iters=50 * max_iters, block=64)
+def _measure(devices: int, X, y, Q, fref: float, block: int, tol: float,
+             max_iters: int) -> dict:
+    mesh = make_conquer_mesh("i", jax.devices()[:devices])
+    n = X.shape[0]
     f = lambda a: float(0.5 * a @ Q @ a - a.sum())
-    fref = f(ref.alpha)
-
-    out = {"devices": devices, "n": n, "block": block, "tol": tol}
-    base = ConquerConfig(kernel=kern, C=4.0, tol=tol, max_iters=max_iters,
-                         block=block, mode="parallel")
+    out = {"devices": devices}
+    base = ConquerConfig(kernel=Kernel("rbf", gamma=8.0), C=4.0, tol=tol,
+                         max_iters=max_iters, block=block, mode="parallel")
     for mode in ("parallel", "replicated"):
         cfg = dataclasses.replace(base, mode=mode)
         # warm call compiles; the timed call measures the solve alone
@@ -70,33 +59,30 @@ def _worker(devices: int, n: int, block: int, tol: float,
             "pg_max": float(pg),
             "rel_obj_err": abs(f(alpha) - fref) / abs(fref),
         }
-    print("DISTBENCH::" + json.dumps(out), flush=True)
+    return out
 
 
 def run(dry_run: bool = False) -> list:
     n, block, tol = (768, 16, 1e-3) if dry_run else (4096, 16, 1e-3)
     max_iters = 4000 if dry_run else 20000
-    counts = [1, 8] if dry_run else DEVICE_COUNTS
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    env.pop("XLA_FLAGS", None)
+    avail = jax.device_count()
+    counts = [p for p in ([1, 8] if dry_run else DEVICE_COUNTS)
+              if p <= avail]
+    if counts[-1] < min(avail, 8):
+        counts.append(min(avail, 8))
+
+    kern = Kernel("rbf", gamma=8.0)
+    X, y = gaussian_mixture(jax.random.PRNGKey(0), n, d=D_FEAT,
+                            modes_per_class=4)
+    Q = (y[:, None] * y[None, :]) * gram(kern, X, X)
+    ref = solve_with_shrinking(Q, 4.0, tol=tol / 10.0,
+                               max_iters=50 * max_iters, block=64)
+    fref = float(0.5 * ref.alpha @ Q @ ref.alpha - ref.alpha.sum())
 
     results = {"n": n, "block": block, "tol": tol, "per_devices": {}}
     rows = []
     for devices in counts:
-        cmd = [sys.executable, "-m", "benchmarks.bench_dist", "--worker",
-               "--devices", str(devices), "--n", str(n),
-               "--block", str(block), "--tol", str(tol),
-               "--max-iters", str(max_iters)]
-        out = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                             timeout=3600)
-        if out.returncode != 0:
-            raise RuntimeError(
-                f"bench_dist worker (devices={devices}) failed:\n"
-                f"{out.stdout}\n{out.stderr}")
-        line = next(l for l in out.stdout.splitlines()
-                    if l.startswith("DISTBENCH::"))
-        rec = json.loads(line[len("DISTBENCH::"):])
+        rec = _measure(devices, X, y, Q, fref, block, tol, max_iters)
         results["per_devices"][str(devices)] = rec
         for mode in ("parallel", "replicated"):
             m = rec[mode]
@@ -109,40 +95,22 @@ def run(dry_run: bool = False) -> list:
     # the headline claim: P simultaneous blocks -> strictly fewer
     # communication rounds than one global block at the same tolerance
     top = results["per_devices"][str(counts[-1])]
-    assert top["parallel"]["rounds"] < top["replicated"]["rounds"], top
+    if counts[-1] > 1:
+        assert top["parallel"]["rounds"] < top["replicated"]["rounds"], top
     results["rounds_ratio_at_max_devices"] = (
         top["replicated"]["rounds"] / top["parallel"]["rounds"])
 
     # bytes-per-round accounting (DESIGN.md §11): both modes gather O(P*B*d)
     # per round; parallel applies P*B coordinate updates per round instead
     # of B, so descent per byte scales with P
-    d_feat = 8
     results["bytes_per_round_model"] = {
-        "all_gather_floats": counts[-1] * block * (d_feat + 2),
+        "all_gather_floats": counts[-1] * block * (D_FEAT + 2),
         "updates_per_round": {"parallel": counts[-1] * block,
                               "replicated": block},
     }
-
-    from benchmarks.common import emit_json
     emit_json("BENCH_dist.json", results)
     return rows
 
 
-def main() -> None:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--worker", action="store_true")
-    ap.add_argument("--devices", type=int, default=1)
-    ap.add_argument("--n", type=int, default=4096)
-    ap.add_argument("--block", type=int, default=16)
-    ap.add_argument("--tol", type=float, default=1e-3)
-    ap.add_argument("--max-iters", type=int, default=20000)
-    args = ap.parse_args()
-    if args.worker:
-        _worker(args.devices, args.n, args.block, args.tol, args.max_iters)
-    else:
-        from benchmarks.common import emit
-        emit(run())
-
-
 if __name__ == "__main__":
-    main()
+    emit(run())

@@ -36,7 +36,10 @@ def emit_json(path: str, payload: dict, merge: bool = False) -> None:
             base = {}
         if isinstance(base, dict):
             payload = {**base, **payload}
-    payload = dict(payload, backend=jax.default_backend())
+    dev = jax.devices()[0]
+    payload = dict(payload, backend=dev.platform,
+                   device={"platform": dev.platform, "kind": dev.device_kind,
+                           "count": jax.device_count()})
     with open(path, "w") as f:
         json.dump(payload, f, indent=2, sort_keys=True)
         f.write("\n")

@@ -13,6 +13,7 @@ import time
 import traceback
 
 from benchmarks.common import emit
+from repro.launch.compile_cache import enable_compile_cache
 
 ALL = ["fig1", "fig2", "fig3", "table1", "table3", "table6", "kernels",
        "outofcore", "trace", "serve", "slo", "svr", "oneclass", "eq_block",
@@ -20,6 +21,7 @@ ALL = ["fig1", "fig2", "fig3", "table1", "table3", "table6", "kernels",
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     ap.add_argument("--dry-run", action="store_true",

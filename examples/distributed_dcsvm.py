@@ -25,11 +25,12 @@ import jax.numpy as jnp
 from repro.core import DCSVMConfig, Kernel, gram, kkt_residual
 from repro.core.distributed import ConquerConfig, conquer_step, fit_distributed
 from repro.data import gaussian_mixture
+from repro.launch.mesh import make_conquer_mesh
 
 
 def main():
     print(f"devices: {jax.device_count()}")
-    mesh = jax.make_mesh((jax.device_count(),), ("i",))
+    mesh = make_conquer_mesh("i")
     kern = Kernel("rbf", gamma=8.0)
     X, y = gaussian_mixture(jax.random.PRNGKey(0), 4096, d=8, modes_per_class=4)
     C = 4.0

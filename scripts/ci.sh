@@ -4,8 +4,8 @@
 # caught early.
 #
 #   scripts/ci.sh            # full tier-1 + kernels/serve/slo/svr/oneclass/
-#                            # eq-block/dist bench smoke (dist spawns 1- and
-#                            # 8-forced-host-device subprocesses)
+#                            # eq-block/dist bench smoke (on 8 virtual CPU
+#                            # devices, so dist meshes 1 and 8 of them)
 #   scripts/ci.sh --fast     # quick local loop: tests only, and the
 #                            # hypothesis-backed property suite is skipped
 #                            # via its pytest marker (-m "not properties")
@@ -123,13 +123,13 @@ else
     # benchmarks smoke: tiny shapes, asserts Pallas/XLA parity on every
     # kernel, on the conquer solver, on the generalized SVR + one-class
     # duals, on the blocked (rank-2B) vs pairwise equality engines, on the
-    # sharded parallel-block conquer (multi-device subprocesses assert
+    # sharded parallel-block conquer (one process over 8 virtual devices:
     # fewer rounds-to-tol than the replicated baseline at 8 devices), on
     # the GramOperator precision/spill tiers, and on the traced-vs-untraced
     # conquer (trace asserts bit-identity and emits the pg_max-vs-seconds
     # curve; kernels/outofcore/trace all merge sections into
     # BENCH_conquer.json); writes BENCH_{conquer,serve,svr,oneclass,dist}.json
-    python -m benchmarks.run \
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 python -m benchmarks.run \
         --only kernels,outofcore,trace,serve,slo,svr,oneclass,eq_block,dist \
         --dry-run
 fi

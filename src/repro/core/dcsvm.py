@@ -34,8 +34,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core.kernels import (DEFAULT_GRAM_BUDGET, Kernel, gram,
-                                gram_matvec, resolve_use_pallas)
+from repro.core.kernels import (DEFAULT_GRAM_BUDGET, HIGHEST, Kernel,
+                                f32_matmul, gram, gram_matvec,
+                                resolve_use_pallas)
 from repro.core.kkmeans import Partition, two_step_kernel_kmeans
 from repro.core import gramop
 from repro.core import solver as S
@@ -631,7 +632,7 @@ def _recover_rho_clusters(cfg: DCSVMConfig, td: TaskDual, task: Task,
         mm = mi[:, None] & mi[None, :]
         Kz = jnp.where(mm, Ki, 0.0)
         ui = jnp.where(mi, ui, 0.0)
-        gi = si * (Kz @ (si * ui)) + pi
+        gi = si * f32_matmul(Kz, si * ui) + pi
         return task.recover_offset(ui, gi, jnp.where(mi, ci, 0.0),
                                    jnp.where(mi, ai, 0.0), gi_,
                                    active_mask=mi)
@@ -709,4 +710,5 @@ def objective_value(cfg: DCSVMConfig, X: Array, y: Array, alpha: Array,
                      compute_dtype=cfg.compute_dtype,
                      budget_bytes=cfg.gram_budget)
     pvec = jnp.broadcast_to(jnp.asarray(p, alpha.dtype), alpha.shape)
-    return 0.5 * jnp.vdot(alpha, y * Kv) + jnp.vdot(pvec, alpha)
+    return (0.5 * jnp.vdot(alpha, y * Kv, precision=HIGHEST)
+            + jnp.vdot(pvec, alpha, precision=HIGHEST))

@@ -60,12 +60,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.compat import shard_map
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from repro.core import colcache, gramop
-from repro.core.kernels import Kernel, gram, resolve_use_pallas
+from repro.core.kernels import (HIGHEST, Kernel, f32_matmul, gram,
+                                resolve_use_pallas)
 from repro.core.solver import (_solve_small_qp, combination_step_size,
                                proj_grad)
 from repro.core import solver as S
@@ -75,6 +75,21 @@ from repro.obs.trace import (ConvTrace, trace_fetch, trace_init,
 from repro.obs.spans import span
 
 Array = jax.Array
+
+
+def _varying(tree, axis: str):
+    """Mark every leaf of ``tree`` as varying over the manual ``axis``.
+
+    ``shard_map`` checks that a loop carry (and both branches of a ``cond``)
+    keep one varying-axes type; state built from constants starts out
+    device-invariant and turns varying once a per-device value is written
+    into it, so carries are cast up front.  Leaves that already vary pass
+    through (``pcast`` refuses varying -> varying)."""
+    def one(x):
+        if axis in jax.typeof(x).vma:
+            return x
+        return lax.pcast(x, (axis,), to="varying")
+    return jax.tree.map(one, tree)
 
 
 # ---------------------------------------------------------------------------
@@ -141,12 +156,14 @@ def divide_step(
         return lax.map(lambda t: one(*t), (Xl, sl, pl, cl, al, ml))
 
     spec = P(axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec,) * 6,
         out_specs=spec,
     )
-    return fn(Xc, sc, pc, cc, ac, mask)
+    # the caller's arrays may be committed to one device: place them
+    return fn(*jax.device_put((Xc, sc, pc, cc, ac, mask),
+                              NamedSharding(mesh, spec)))
 
 
 # ---------------------------------------------------------------------------
@@ -258,7 +275,7 @@ def conquer_step(
         chunks = max(1, min(cfg.grad_chunks, nl))
         padl = (-nl) % chunks
         Xp = jnp.pad(Xl, ((0, padl), (0, 0))) if padl else Xl
-        out = lax.map(lambda Xi: pairwise(Xi, Z) @ w,
+        out = lax.map(lambda Xi: f32_matmul(pairwise(Xi, Z), w),
                       Xp.reshape(chunks, -1, d))
         return out.reshape(-1)[:nl]
 
@@ -283,7 +300,7 @@ def conquer_step(
                 return kops.cd_column_update(
                     Xl, sl, Xsel, w, kernel,
                     compute_dtype=compute_dtype).astype(acc)
-            return (sl * (pairwise(Xl, Xsel) @ w)).astype(acc)
+            return (sl * f32_matmul(pairwise(Xl, Xsel), w)).astype(acc)
 
         def propose(al, g_l):
             """One CE-PBM proposal: local GS-B block, local BxB solve, one
@@ -320,8 +337,9 @@ def conquer_step(
                     + gath["i"]).reshape(-1)
             Qsel = ((ssel[:, None] * ssel[None, :])
                     * pairwise(Xsel, Xsel)).astype(acc)
-            dQd = jnp.vdot(dsel, Qsel @ dsel)
-            gTd = lax.psum(jnp.vdot(gb.astype(acc), delta), axis)
+            dQd = jnp.vdot(dsel, f32_matmul(Qsel, dsel), precision=HIGHEST)
+            gTd = lax.psum(jnp.vdot(gb.astype(acc), delta,
+                                    precision=HIGHEST), axis)
             gamma = combination_step_size(gTd, dQd)
             a_new = (ab.astype(acc) + gamma * delta).astype(dtype)
             eps = (0.1 * cfg.tol * (1.0 + cb)).astype(dtype)
@@ -357,12 +375,14 @@ def conquer_step(
             """One post-update sample per round; the psum-reduced columns
             make every device's ring identical, so the caller reads shard 0."""
             alc = al.astype(acc)
-            obj = lax.psum(0.5 * jnp.vdot(alc, g_l)
-                           + 0.5 * jnp.vdot(pl.astype(acc), alc), axis)
+            obj = lax.psum(0.5 * jnp.vdot(alc, g_l, precision=HIGHEST)
+                           + 0.5 * jnp.vdot(pl.astype(acc), alc,
+                                            precision=HIGHEST), axis)
             nfree = lax.psum(jnp.sum(((al > 0.0) & (al < cl) & vl)
                                      .astype(jnp.int32)), axis)
-            return trace_record(tr, pg_max=pg, objective=obj, n_free=nfree,
-                                gamma=gamma, cache_hits=cache_hits)
+            return _varying(trace_record(tr, pg_max=pg, objective=obj,
+                                         n_free=nfree, gamma=gamma,
+                                         cache_hits=cache_hits), axis)
 
         pg0 = lax.pmax(jnp.max(scores_of(al, g_l)), axis)
         tr = None
@@ -390,7 +410,7 @@ def conquer_step(
                     return al, g_l, it + 1, pg, tr
 
                 state0 = (al, g_l, jnp.zeros((), jnp.int32), pg0,
-                          trace_init(tcap))
+                          _varying(trace_init(tcap), axis))
                 al, g_l, rounds, _, tr = lax.while_loop(cond_t, body, state0)
 
         elif cfg.mode == "parallel":
@@ -406,7 +426,7 @@ def conquer_step(
                 )
                 cache = colcache.update(cache, gidx, Qrows, served, slots,
                                         hit)
-                g_l = g_l + asel @ Qrows
+                g_l = g_l + f32_matmul(asel, Qrows)
                 al = al.at[ib].set(a_new)
                 return al, g_l, cache, pg, gamma
 
@@ -414,7 +434,8 @@ def conquer_step(
             # fits twice the rows of f32 under the same byte budget
             store = (jnp.dtype(compute_dtype) if compute_dtype is not None
                      else acc)
-            cache0 = colcache.init(cache_cap, n, dtype=store, width=n_l)
+            cache0 = _varying(colcache.init(cache_cap, n, dtype=store,
+                                            width=n_l), axis)
 
             if tcap == 0:
                 def body(state):
@@ -436,7 +457,7 @@ def conquer_step(
                     return al, g_l, cache, it + 1, pg, tr
 
                 state0 = (al, g_l, cache0, jnp.zeros((), jnp.int32), pg0,
-                          trace_init(tcap))
+                          _varying(trace_init(tcap), axis))
                 al, g_l, _, rounds, _, tr = lax.while_loop(cond_t, body,
                                                            state0)
 
@@ -488,7 +509,7 @@ def conquer_step(
                     return al, g_l, it + 1, pg, tr
 
                 state0 = (al, g_l, jnp.zeros((), jnp.int32), pg0,
-                          trace_init(tcap))
+                          _varying(trace_init(tcap), axis))
                 al, g_l, rounds, _, tr = lax.while_loop(cond_t, body, state0)
 
         # residual at the RETURNED alpha, not the pre-update stopping value
@@ -501,13 +522,14 @@ def conquer_step(
 
     spec = P(axis)
     traced = cfg.trace_cap > 0
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec,) * 6,
         out_specs=(spec, P(axis), P(axis)) + ((P(axis), P(axis)) if traced
                                               else ()),
     )
-    out = fn(X, s, alpha0, pvec, cvec, vvec)
+    out = fn(*jax.device_put((X, s, alpha0, pvec, cvec, vvec),
+                             NamedSharding(mesh, spec)))
     alpha, rounds, pg = out[:3]
     if traced:
         return (alpha[:n0], rounds[0], jnp.max(pg),
@@ -553,7 +575,8 @@ def fit_distributed(
     pads internally.  The pipeline is device-resident between levels: SV
     detection is a scatter-add over ``base_index`` on device and the
     adaptive kmeans sample draws on device, so alpha never round-trips
-    through NumPy.  Returns ``(alpha (n_dual,), stats list)``.
+    through NumPy.  Returns ``(alpha (n_dual,), stats list)``, alpha on the
+    mesh's first device.
     """
     from repro.core.kkmeans import Partition, two_step_kernel_kmeans
 
@@ -580,6 +603,11 @@ def fit_distributed(
     s1, p1, c1 = td.S[0], td.P[0], td.Cvec[0]
     use_pallas = resolve_use_pallas(cfg.use_pallas)
     P_ = mesh.shape[axis]
+    # the fit's own state (alpha, SV mass, kmeans samples) lives on ONE
+    # device of the mesh and only the shard_mapped steps span it: a Pallas
+    # kernel called on arrays sharded over several devices cannot be
+    # partitioned, so a sharded step output must not leak into kmeans
+    home = SingleDeviceSharding(mesh.devices.flat[0])
     key = jax.random.PRNGKey(cfg.seed)
     alpha = jnp.zeros(nd, X.dtype)
     sv_base = None            # (n,) on-device SV mass per base point
@@ -611,7 +639,7 @@ def fit_distributed(
             ac = divide_step(mesh, axis, cfg, dpart.gather(td.Xd),
                              dpart.gather(s1), dpart.gather(p1),
                              dpart.gather(c1), ac, mask)
-            alpha = dpart.scatter(ac, nd)
+            alpha = dpart.scatter(jax.device_put(ac, home), nd)
         # device-resident SV tracking: dual mass scatter-added per base
         # point (the box family keeps alpha >= 0, so mass > 0 <=> any SV)
         sv_base = jnp.zeros(n, X.dtype).at[bidx].add(alpha)
@@ -627,7 +655,7 @@ def fit_distributed(
                          trace_cap=trace_cap)
     with span("conquer/distributed"):
         out = conquer_step(mesh, axis, ccfg, td.Xd, s1, alpha, p=p1, c=c1)
-        alpha, rounds, pg = out[:3]
+        alpha, rounds, pg = jax.device_put(out[:3], home)
     sv_base = jnp.zeros(n, X.dtype).at[bidx].add(alpha)
     st0 = dict(level=0, rounds=rounds, pg_max=pg,
                n_sv=jnp.sum(sv_base > 0))

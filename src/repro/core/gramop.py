@@ -50,7 +50,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.kernels import (DEFAULT_GRAM_BUDGET, Kernel, auto_num_chunks,
-                                gram_matvec)
+                                f32_matmul, gram_matvec)
 
 Array = jax.Array
 
@@ -209,7 +209,7 @@ class GramOperator:
                 self.Xd, self.s, Xsel, ssel * delta, self.kernel,
                 compute_dtype=self.compute_dtype).astype(g.dtype)
         Qb = self.q_block(idx).astype(g.dtype)
-        return g + Qb @ delta
+        return g + f32_matmul(Qb, delta)
 
 
 jax.tree_util.register_pytree_node(
@@ -268,7 +268,7 @@ def _panel_block_cd(op: GramOperator, tile: Array, pstart, alpha: Array,
         new_ab = _solve_small_qp(Qrows[:, sel], g[sel], ab, cb, sweeps)
         delta = jnp.where(valid, new_ab - ab, 0.0)
         alpha = alpha.at[sel].add(delta.astype(alpha.dtype))
-        g = g + delta @ Qrows
+        g = g + f32_matmul(delta, Qrows)
         return alpha, g, it + 1, panel_pg(alpha, g)
 
     def cond(state):

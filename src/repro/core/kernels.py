@@ -30,6 +30,19 @@ Array = jax.Array
 # spill panels against.
 DEFAULT_GRAM_BUDGET = 2 ** 29
 
+# f32 means f32 on every backend.  At DEFAULT precision the TPU (XLA and
+# Mosaic alike) may run an f32 contraction as one bf16 pass: the RBF
+# expansion xx + yy - 2<x, y> then cancels catastrophically and the solvers'
+# gradient matvecs drift by more than the stopping tolerance.  Every f32
+# contraction of the solver and serving paths asks for HIGHEST; CPU computes
+# f32 exactly either way, so the CPU results are unchanged.
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def f32_matmul(a: Array, b: Array) -> Array:
+    """``a @ b`` at full f32 precision (see ``HIGHEST``)."""
+    return jnp.matmul(a, b, precision=HIGHEST)
+
 
 def auto_num_chunks(n_rows: int, n_cols: int, itemsize: int = 4,
                     budget_bytes: Optional[int] = None) -> int:
@@ -75,9 +88,10 @@ class Kernel:
         cd = _resolve_cd(compute_dtype, X.dtype)
         if cd is None:
             if self.kind == "linear":
-                return X @ Y.T
+                return f32_matmul(X, Y.T)
             if self.kind == "poly":
-                return (self.gamma * (X @ Y.T) + self.coef0) ** self.degree
+                return ((self.gamma * f32_matmul(X, Y.T) + self.coef0)
+                        ** self.degree)
             return jnp.exp(-self.gamma * sqdist(X, Y))
         Xc, Yc = X.astype(cd), Y.astype(cd)
         g = jax.lax.dot_general(Xc, Yc, (((1,), (1,)), ((), ())),
@@ -110,7 +124,7 @@ def sqdist(X: Array, Y: Array) -> Array:
     """Squared euclidean distances via the Gram expansion (MXU-friendly)."""
     xx = jnp.sum(X * X, axis=-1)[:, None]
     yy = jnp.sum(Y * Y, axis=-1)[None, :]
-    sq = xx + yy - 2.0 * (X @ Y.T)
+    sq = xx + yy - 2.0 * f32_matmul(X, Y.T)
     return jnp.maximum(sq, 0.0)
 
 
@@ -178,7 +192,8 @@ def gram_matvec(kernel: Kernel, X: Array, v: Array,
     Xr = Xp.reshape(num_chunks, rows, -1)
 
     def one(Xi):
-        return kernel.pairwise(Xi, X, compute_dtype=compute_dtype) @ v
+        return f32_matmul(
+            kernel.pairwise(Xi, X, compute_dtype=compute_dtype), v)
 
     return jax.lax.map(one, Xr).reshape(-1)[:n]
 

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.kernels import Kernel, gram
+from repro.core.kernels import HIGHEST, Kernel, f32_matmul, gram
 
 Array = jax.Array
 
@@ -50,8 +50,8 @@ def _center_terms(Kmm: Array, assign: Array, k: int) -> Tuple[Array, Array]:
     H = jax.nn.one_hot(assign, k, dtype=Kmm.dtype)              # (m, k)
     counts = jnp.maximum(H.sum(axis=0), 1.0)
     W = H / counts[None, :]
-    M = Kmm @ W                                                 # (m, k)
-    s = jnp.einsum("mk,mk->k", W, M)
+    M = f32_matmul(Kmm, W)                                              # (m, k)
+    s = jnp.einsum("mk,mk->k", W, M, precision=HIGHEST)
     return W, s
 
 
@@ -66,7 +66,7 @@ def kernel_kmeans(Kmm: Array, k: int, key: Array, iters: int = 20) -> Tuple[Arra
 
     def body(_, assign):
         W, s = _center_terms(Kmm, assign, k)
-        D = diag[:, None] - 2.0 * (Kmm @ W) + s[None, :]
+        D = diag[:, None] - 2.0 * f32_matmul(Kmm, W) + s[None, :]
         new_assign = jnp.argmin(D, axis=1).astype(jnp.int32)
         # reseed ALL empty clusters in one shot: the e-th empty cluster takes
         # the e-th point farthest from its own center.  Reseeding one per
@@ -106,7 +106,7 @@ def assign_points(
     to +inf so only populated centers are routable.
     """
     Knm = gram(kernel, X, model.Xm, use_pallas=use_pallas)      # (n, m)
-    D = kernel.diag(X)[:, None] - 2.0 * (Knm @ model.W) + model.s[None, :]
+    D = kernel.diag(X)[:, None] - 2.0 * f32_matmul(Knm, model.W) + model.s[None, :]
     empty = jnp.sum(model.W, axis=0) <= 0.0                     # (k,)
     D = jnp.where(empty[None, :], jnp.inf, D)
     return jnp.argmin(D, axis=1).astype(jnp.int32), D

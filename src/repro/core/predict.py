@@ -28,7 +28,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.dcsvm import DCSVMModel
-from repro.core.kernels import Kernel, gram, resolve_use_pallas
+from repro.core.kernels import (Kernel, f32_matmul, gram,
+                                resolve_use_pallas)
 from repro.core.kkmeans import KKMeansModel, assign_points
 
 Array = jax.Array
@@ -83,11 +84,12 @@ def bucketed_cluster_scores(kern: Kernel, Xq: Array, cid: Array,
         from repro.kernels import ops as kops
 
         def one(qc, Xc, wc):
-            return kops.kernel_matrix(qc, Xc, kern,
-                                      compute_dtype=compute_dtype) @ wc
+            return f32_matmul(kops.kernel_matrix(qc, Xc, kern,
+                                         compute_dtype=compute_dtype), wc)
     else:
         def one(qc, Xc, wc):
-            return kern.pairwise(qc, Xc, compute_dtype=compute_dtype) @ wc
+            return f32_matmul(
+                kern.pairwise(qc, Xc, compute_dtype=compute_dtype), wc)
 
     def body(carry):
         out, r = carry
@@ -148,7 +150,7 @@ def _decision_scan(kern: Kernel, Xq: Array, Xs: Array, W: Array,
         Kc = (kops.kernel_matrix(Xq, Xc, kern, compute_dtype=compute_dtype)
               if use_pallas
               else kern.pairwise(Xq, Xc, compute_dtype=compute_dtype))
-        return acc + Kc @ wc, None
+        return acc + f32_matmul(Kc, wc), None
 
     out, _ = jax.lax.scan(
         step, jnp.zeros((Xq.shape[0], W.shape[1]), Xq.dtype),

@@ -72,7 +72,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core import colcache, gramop
-from repro.core.kernels import Kernel
+from repro.core.kernels import HIGHEST, Kernel, f32_matmul
 from repro.obs.trace import ConvTrace, trace_record
 
 Array = jax.Array
@@ -107,7 +107,7 @@ def objective(alpha: Array, grad: Array, p=-1.0) -> Array:
     The default ``p = -1`` recovers the hinge form 1/2 a'g - 1/2 e'a.
     """
     pu = jnp.sum(jnp.asarray(p, alpha.dtype) * alpha)
-    return 0.5 * jnp.vdot(alpha, grad) + 0.5 * pu
+    return 0.5 * jnp.vdot(alpha, grad, precision=HIGHEST) + 0.5 * pu
 
 
 def _n_free(alpha: Array, cvec: Array, mask: Optional[Array] = None) -> Array:
@@ -129,7 +129,7 @@ def proj_grad(alpha: Array, grad: Array, C) -> Array:
 
 
 def kkt_residual(Q: Array, alpha: Array, C, p=-1.0) -> Array:
-    g = Q @ alpha + jnp.asarray(p, alpha.dtype)
+    g = f32_matmul(Q, alpha) + jnp.asarray(p, alpha.dtype)
     return jnp.max(jnp.abs(proj_grad(alpha, g, C)))
 
 
@@ -191,7 +191,7 @@ def solve_box_qp(
     alpha = jnp.zeros(n, Q.dtype) if alpha0 is None else alpha0
     cvec = _broadcast(C, n, Q.dtype)
     pvec = _broadcast(p, n, Q.dtype)
-    g = Q @ alpha + pvec
+    g = f32_matmul(Q, alpha) + pvec
     mask = jnp.ones(n, bool) if active_mask is None else active_mask
 
     def step(alpha, g):
@@ -286,7 +286,7 @@ def solve_box_qp_block(
     alpha = jnp.zeros(n, Q.dtype) if alpha0 is None else alpha0
     cvec = _broadcast(C, n, Q.dtype)
     pvec = _broadcast(p, n, Q.dtype)
-    g = Q @ alpha + pvec
+    g = f32_matmul(Q, alpha) + pvec
     mask = jnp.ones(n, bool) if active_mask is None else active_mask
 
     def step(alpha, g):
@@ -297,7 +297,8 @@ def solve_box_qp_block(
         ab, gb = alpha[idx], g[idx]
         new_ab = _solve_small_qp(Qbb, gb, ab, cvec[idx], sweeps)
         delta = new_ab - ab
-        return alpha.at[idx].set(new_ab), g + Q[:, idx] @ delta, jnp.max(scores)
+        return (alpha.at[idx].set(new_ab), g + f32_matmul(Q[:, idx], delta),
+                jnp.max(scores))
 
     pg0 = jnp.max(jnp.abs(jnp.where(mask, proj_grad(alpha, g, cvec), 0.0)))
 
@@ -463,7 +464,8 @@ def solve_box_qp_op(
             cache = colcache.update(cache, keys, kr, served, slots, hit)
             Qrows = op.expand_rows(kr, idx)
             new_ab, delta = solve_block(Qrows[:, idx], alpha, g, idx)
-            return alpha.at[idx].set(new_ab), g + delta @ Qrows, cache, pg_max
+            return (alpha.at[idx].set(new_ab), g + f32_matmul(delta, Qrows),
+                    cache, pg_max)
 
         pg0 = jnp.max(jnp.abs(proj_grad(alpha, g, cvec)))
         cache0 = colcache.init(cap, op.kwidth, dtype=op.storage_dtype(acc),
@@ -515,7 +517,7 @@ def solve_box_qp_op(
             Qb = op.q_block(idx).astype(acc)         # (n, B) on the fly
             Qbb = Qb[idx]                            # slice, don't recompute
             new_ab, delta = solve_block(Qbb, alpha, g, idx)
-            return alpha.at[idx].set(new_ab), g + Qb @ delta, pg_max
+            return alpha.at[idx].set(new_ab), g + f32_matmul(Qb, delta), pg_max
 
     pg0 = jnp.max(jnp.abs(proj_grad(alpha, g, cvec)))
 
@@ -685,7 +687,7 @@ def kkt_residual_eq(Q: Array, alpha: Array, C, a, p=0.0, gid=None,
     """Maximal-violating-pair gap at ``alpha`` on the FULL problem (the
     equality-family analogue of ``kkt_residual``), maximized over the
     constraint groups; 0 at any KKT point."""
-    g = Q @ alpha + jnp.asarray(p, alpha.dtype)
+    g = f32_matmul(Q, alpha) + jnp.asarray(p, alpha.dtype)
     rho_lo, rho_hi = equality_interval_grouped(alpha, g, C, a, gid, n_groups)
     return jnp.maximum(jnp.max(rho_lo - rho_hi), 0.0)
 
@@ -748,7 +750,7 @@ def project_box_equality(alpha: Array, C, a, d,
         return jnp.clip(base - t * amove, 0.0, cvec)
 
     def resid(t):
-        return jnp.vdot(avec, at_t(t)) - d
+        return jnp.vdot(avec, at_t(t), precision=HIGHEST) - d
 
     # |t| >= c_i / |a_i| saturates every moving coordinate
     T = jnp.max(jnp.where(amove != 0.0,
@@ -814,7 +816,8 @@ def _restore_equality(alpha: Array, grad: Array, Q_col, cvec: Array,
     O(||Q|| drift).  Falls back to any maskable coordinate when the iterate
     is a vertex.  ``Q_col(k)`` returns column k of Q for the gradient fix-up.
     """
-    r = jnp.vdot(avec, alpha) - jnp.asarray(d, alpha.dtype)
+    r = (jnp.vdot(avec, alpha, precision=HIGHEST)
+         - jnp.asarray(d, alpha.dtype))
     cand = jnp.clip(alpha - r / _safe_a(avec), 0.0, cvec)
     resid = r + avec * (cand - alpha)
     ok = mask & (avec != 0.0)
@@ -1023,7 +1026,7 @@ def solve_eq_qp(
         qdiag=jnp.diagonal(Q),
         qij_fn=lambda i, j: Q[i, j],
         rank2_fn=lambda g, i, j, di, dj: g + di * Q[:, i] + dj * Q[:, j],
-        full_grad=lambda al: Q @ al + pvec,
+        full_grad=lambda al: f32_matmul(Q, al) + pvec,
         tol=tol, max_iters=max_iters, refresh_every=refresh_every,
         trace=trace, pvec=pvec)
     alpha, g, iters, pg_max = out[:4]
@@ -1270,8 +1273,8 @@ def solve_eq_qp_block(
     out = _blocked_mvp_loop(
         alpha, cvec, avec, mask, gidv, n_groups, B, sweeps,
         qbb_fn=lambda idx: Q[idx][:, idx],
-        rank2b_fn=lambda g, idx, delta: g + Q[:, idx] @ delta,
-        full_grad=lambda al: Q @ al + pvec,
+        rank2b_fn=lambda g, idx, delta: g + f32_matmul(Q[:, idx], delta),
+        full_grad=lambda al: f32_matmul(Q, al) + pvec,
         tol=tol, max_iters=max_iters, refresh_every=refresh_every,
         trace=trace, pvec=pvec)
     alpha, g, iters, pg_max = out[:4]

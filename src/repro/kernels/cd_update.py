@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import out_struct, precision
+
 
 def _cd_body(x_ref, y_ref, xb_ref, w_ref, o_ref, *, kind: str, gamma: float,
              degree: int, coef0: float, compute_dtype=None):
@@ -36,7 +38,8 @@ def _cd_body(x_ref, y_ref, xb_ref, w_ref, o_ref, *, kind: str, gamma: float,
         x = x.astype(compute_dtype)
         xb = xb.astype(compute_dtype)
     g = jax.lax.dot_general(x, xb, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=precision(compute_dtype))
     if kind == "linear":
         k = g
     elif kind == "poly":
@@ -46,7 +49,8 @@ def _cd_body(x_ref, y_ref, xb_ref, w_ref, o_ref, *, kind: str, gamma: float,
         bb = jnp.sum(xb.astype(jnp.float32) ** 2, axis=-1)[None, :]
         k = jnp.exp(-gamma * jnp.maximum(xx + bb - 2.0 * g, 0.0))
     w = w_ref[...]                                      # (B, 1)
-    o = y_ref[...] * jnp.dot(k, w, preferred_element_type=jnp.float32)
+    o = y_ref[...] * jnp.dot(k, w, preferred_element_type=jnp.float32,
+                             precision=jax.lax.Precision.HIGHEST)
     o_ref[...] = o.astype(o_ref.dtype)
 
 
@@ -85,7 +89,7 @@ def cd_column_update(
             pl.BlockSpec((B, 1), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
+        out_shape=out_struct((n, 1), jnp.float32, X, y, Xb, w),
         interpret=interpret,
     )(X, y[:, None], Xb, w[:, None])
     return out[:, 0]

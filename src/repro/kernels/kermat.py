@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import out_struct, precision
+
 
 def _kermat_body(x_ref, y_ref, o_ref, *, kind: str, gamma: float, degree: int,
                  coef0: float, compute_dtype=None):
@@ -32,7 +34,8 @@ def _kermat_body(x_ref, y_ref, o_ref, *, kind: str, gamma: float, degree: int,
         x = x.astype(compute_dtype)
         y = y.astype(compute_dtype)
     g = jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=precision(compute_dtype),
     )                                                        # (bm, bn) MXU
     if kind == "linear":
         o = g
@@ -80,6 +83,6 @@ def kermat(
             pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n, m), jnp.float32),
+        out_shape=out_struct((n, m), jnp.float32, X, Y),
         interpret=interpret,
     )(X, Y)

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import out_struct, precision
+
 
 def _kmv_body(x_ref, z_ref, v_ref, o_ref, *, kind: str, gamma: float,
               degree: int, coef0: float, compute_dtype=None):
@@ -40,7 +42,8 @@ def _kmv_body(x_ref, z_ref, v_ref, o_ref, *, kind: str, gamma: float,
         x = x.astype(compute_dtype)
         z = z.astype(compute_dtype)
     g = jax.lax.dot_general(x, z, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=precision(compute_dtype))
     if kind == "linear":
         k = g
     elif kind == "poly":
@@ -49,7 +52,8 @@ def _kmv_body(x_ref, z_ref, v_ref, o_ref, *, kind: str, gamma: float,
         xx = jnp.sum(x.astype(jnp.float32) ** 2, axis=-1)[:, None]
         zz = jnp.sum(z.astype(jnp.float32) ** 2, axis=-1)[None, :]
         k = jnp.exp(-gamma * jnp.maximum(xx + zz - 2.0 * g, 0.0))
-    o_ref[...] += jnp.dot(k, v_ref[...], preferred_element_type=jnp.float32)
+    o_ref[...] += jnp.dot(k, v_ref[...], preferred_element_type=jnp.float32,
+                          precision=jax.lax.Precision.HIGHEST)
 
 
 @functools.partial(
@@ -86,7 +90,7 @@ def kernel_matvec(
             pl.BlockSpec((bn, 1), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
+        out_shape=out_struct((n, 1), jnp.float32, X, Z, v),
         interpret=interpret,
     )(X, Z, v[:, None])
     return out[:, 0]

@@ -18,18 +18,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import out_struct
+
 
 def _assign_body(x_ref, xm_ref, w_ref, s_ref, scores_ref, assign_ref, *,
                  gamma: float):
     x = x_ref[...]                                     # (bm, d)
     xm = xm_ref[...]                                   # (m, d)
     g = jax.lax.dot_general(x, xm, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
     xx = jnp.sum(x.astype(jnp.float32) ** 2, axis=-1)[:, None]
     mm = jnp.sum(xm.astype(jnp.float32) ** 2, axis=-1)[None, :]
     k = jnp.exp(-gamma * jnp.maximum(xx + mm - 2.0 * g, 0.0))   # (bm, m)
     w = w_ref[...]                                     # (m, kpad)
-    scores = -2.0 * jnp.dot(k, w, preferred_element_type=jnp.float32)
+    scores = -2.0 * jnp.dot(k, w, preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
     scores = scores + s_ref[...]                       # (bm, kpad); pads = +inf
     scores_ref[...] = scores
     assign_ref[...] = jnp.argmin(scores, axis=-1, keepdims=True).astype(jnp.int32)
@@ -69,8 +73,8 @@ def kmeans_assign(
             pl.BlockSpec((bm, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n, kpad), jnp.float32),
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
+            out_struct((n, kpad), jnp.float32, X, Xm, W, s),
+            out_struct((n, 1), jnp.int32, X, Xm, W, s),
         ],
         interpret=interpret,
     )(X, Xm, W, s)

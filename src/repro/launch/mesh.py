@@ -14,20 +14,18 @@ from typing import Dict, Optional, Tuple, Union
 
 import numpy as np
 import jax
-from jax.sharding import Mesh
-
-try:  # AxisType landed after the jax pinned in some containers
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on the installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 MeshAxis = Union[None, str, Tuple[str, ...]]
 
 
-def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
+               devices=None) -> Mesh:
+    """``jax.make_mesh`` defaults to Explicit axes; every solver path
+    (``shard_map`` bodies, partition gathers/scatters) is written for Auto
+    sharding propagation, so every mesh of this repo is built here."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -47,10 +45,12 @@ def make_host_mesh(model_axis: int = 1) -> Mesh:
     return _auto_mesh((n // model_axis, model_axis), ("data", "model"))
 
 
-def make_conquer_mesh(axis: str = "shard") -> Mesh:
-    """Flat 1-axis mesh over every local device — the layout the distributed
-    DC-SVM divide/conquer runs on (rows of the dual sharded over ``axis``)."""
-    return jax.make_mesh((jax.device_count(),), (axis,))
+def make_conquer_mesh(axis: str = "shard", devices=None) -> Mesh:
+    """Flat 1-axis mesh over ``devices`` (default: every local device) — the
+    layout the distributed DC-SVM divide/conquer runs on (rows of the dual
+    sharded over ``axis``)."""
+    devices = list(jax.devices() if devices is None else devices)
+    return _auto_mesh((len(devices),), (axis,), devices=devices)
 
 
 def rules_for(mesh: Mesh) -> Dict[str, MeshAxis]:

@@ -35,7 +35,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.dcsvm import DCSVMConfig, DCSVMModel
-from repro.core.kernels import Kernel, gram, resolve_use_pallas
+from repro.core.kernels import (HIGHEST, Kernel, f32_matmul, gram,
+                                resolve_use_pallas)
 from repro.core.kkmeans import KKMeansModel
 from repro.core.multiclass import MulticlassModel, fit_ova
 from repro.core.predict import _early_program, bucket_size, early_capacity
@@ -235,7 +236,8 @@ def serve_scores_exact(sm: ServingModel, Xq: Array, kern: Kernel,
                        use_pallas: bool = False) -> Array:
     # sm.rho == 0 for non-ocsvm models; every scorer applies its own offset
     # so serve_batch never has to know which strategy already subtracted it
-    return gram(kern, Xq, sm.Xall, use_pallas=use_pallas) @ sm.Wall - sm.rho
+    K = gram(kern, Xq, sm.Xall, use_pallas=use_pallas)
+    return f32_matmul(K, sm.Wall) - sm.rho
 
 
 def serve_scores_early(sm: ServingModel, Xq: Array, kern: Kernel, cap: int,
@@ -259,10 +261,11 @@ def serve_scores_bcm(sm: ServingModel, Xq: Array, kern: Kernel,
     def per_cluster(Xc, Wc, Lc, mc, off):
         Kqs = kern.pairwise(Xq, Xc) * mc[None, :]
         # committee member c votes with ITS local decision f_c - rho_c
-        f = Kqs @ Wc - off                                   # (nq, C)
+        f = f32_matmul(Kqs, Wc) - off                                # (nq, C)
         # Lchol was factored at export: two triangular solves per request
         sol = jax.scipy.linalg.cho_solve((Lc, True), Kqs.T)  # (s, nq)
-        var = jnp.maximum(diag - jnp.einsum("qs,sq->q", Kqs, sol), noise)
+        var = jnp.maximum(
+            diag - jnp.einsum("qs,sq->q", Kqs, sol, precision=HIGHEST), noise)
         prec = jnp.where(jnp.any(mc), 1.0 / var, 0.0)        # skip empty blocks
         return f * prec[:, None], prec
 
@@ -523,6 +526,9 @@ def _serve_async(args, model, Xpool: np.ndarray) -> None:
 
 
 def main(argv=None) -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from repro.core.dcsvm import fit
     from repro.core.predict import accuracy_multiclass, f1, mse, recall
     from repro.core.tasks import EpsilonSVR, OneClassSVM

@@ -50,6 +50,7 @@ from repro.core import (
     recall,
 )
 from repro.core.dcsvm import DCSVMModel
+from repro.launch.compile_cache import enable_compile_cache
 from repro.data import (
     checkerboard, covtype_like, friedman1, gaussian_mixture,
     gaussian_mixture_imbalanced, gaussian_with_outliers, sinc1d,
@@ -81,6 +82,7 @@ def parse_class_weight(spec: str):
 
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--task", default="svc",
                     choices=["svc", "weighted-svc", "svr", "nu-svc",

@@ -250,8 +250,7 @@ def _moe_apply_sharded(
         aux_dr = jax.lax.pmean(aux[2], axes_all)
         return out, aux_lb[None], aux_z[None], aux_dr[None]
 
-    from repro.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, r_spec, w13_spec, w13_spec, w2_spec),
         out_specs=(x_spec, P(None), P(None), P(None)),

@@ -79,8 +79,7 @@ def compressed_psum(x_stacked: Array, mesh: Mesh, axis: str) -> Array:
         total = jnp.sum(deq, axis=0).reshape(-1)
         return total[:n].reshape(shape)[None]
 
-    from repro.compat import shard_map
-    fn = shard_map(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                    in_specs=P(axis, *(None,) * len(shape)),
                    out_specs=P(axis, *(None,) * len(shape)))
     return fn(x_stacked)

@@ -17,7 +17,6 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import (
     DCSVMConfig,
@@ -264,7 +263,7 @@ def test_oneclass_multilevel_matches_dense_reference():
     fit matches a direct dense equality-constrained solve to 1e-4 in
     decision values, and |sum alpha - nu n| <= 1e-6.  x64: at f32 the KKT
     residual itself cannot be measured below ~1e-4 at these scales."""
-    with enable_x64():
+    with jax.enable_x64():
         X, y = _ocsvm_problem(n=400, key=0)
         X = jnp.asarray(X, jnp.float64)
         n = X.shape[0]

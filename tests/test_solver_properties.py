@@ -34,7 +34,6 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import (
     Kernel,
@@ -195,7 +194,7 @@ def test_eq_solver_feasible_kkt_and_vs_reference_x64(seed):
     (f32 cannot even MEASURE a'u to 1e-6 at these scales)."""
     from scipy.optimize import minimize
 
-    with enable_x64():
+    with jax.enable_x64():
         Q, p, c, n = _box_qp(seed, f64=True)
         a, d = _eq_extras(seed, c, n, f64=True)
         an = np.asarray(a)
@@ -261,7 +260,7 @@ def test_eq_block_matches_pairwise_objective(seed):
     reaches the same final objective as the rank-2 pairwise engine to 1e-5
     for B in {1, 2, 8} on the non-tile-aligned conformance grid, while
     staying box- and equality-feasible at the returned iterate."""
-    with enable_x64():
+    with jax.enable_x64():
         Q, p, c, n = _box_qp(seed, f64=True)
         a, d = _eq_extras(seed, c, n, f64=True)
         an = np.asarray(a)
@@ -287,7 +286,7 @@ def test_eq_grouped_two_constraints_vs_slsqp(seed):
     and match a scipy SLSQP solve of the doubly-constrained QP."""
     from scipy.optimize import minimize
 
-    with enable_x64():
+    with jax.enable_x64():
         Q, p, c, n = _box_qp(seed, f64=True)
         a, _ = _eq_extras(seed, c, n, f64=True)
         rng = np.random.default_rng(seed + 7)
@@ -403,7 +402,7 @@ def test_projection_box_equality_properties(seed):
     """project_box_equality output is box-feasible, hits a'u = d for
     attainable targets (x64 exactness), and is a fixed point on already
     feasible inputs."""
-    with enable_x64():
+    with jax.enable_x64():
         rng = np.random.default_rng(seed)
         n = int(rng.choice([12, 24, 40]))
         c = jnp.asarray(rng.uniform(0.2, 2.0, size=n))

@@ -131,7 +131,8 @@ def test_compressed_psum_multi_device_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import numpy as np, jax, jax.numpy as jnp
         from repro.optim.grad_compress import compressed_psum
-        mesh = jax.make_mesh((4,), ("pod",))
+        from repro.launch.mesh import make_conquer_mesh
+        mesh = make_conquer_mesh("pod")
         x = jax.random.normal(jax.random.PRNGKey(0), (4, 300))
         out = compressed_psum(x, mesh, "pod")
         want = jnp.sum(x, 0)
