@@ -502,49 +502,52 @@ def _fit_algorithm1(
             partition = two_step_kernel_kmeans(
                 cfg.kernel, X, kl, sub, m=cfg.m, iters=cfg.kmeans_iters,
                 sample_idx=sample_idx, balanced=cfg.balanced,
-                use_pallas=use_pallas,
+                use_pallas=use_pallas, span_prefix=f"divide/level{l}",
             )
-        # expand the base partition to dual coordinates: SVR's mirrored
-        # (alpha_i, alpha*_i) pair inherits sample i's cluster
-        dpart = partition if nd == n else Partition.build(
-            np.asarray(partition.assign)[base_index].astype(np.int32),
-            kl, partition.model)
         t_cluster = time.perf_counter() - t0
 
         t0 = time.perf_counter()
-        Xcb = lbc = None
-        if cfg.gram_dedup and nd != n:
-            # base-indexed cluster Grams: map each dual slot to its base
-            # point's local slot inside the BASE partition's cluster (the
-            # mirrored pair shares a cluster by construction), so each
-            # cluster computes an (nb, nb) Gram instead of (2nb, 2nb)
-            pidx, pmask = np.asarray(partition.idx), np.asarray(partition.mask)
-            pos = np.zeros(n, np.int64)
-            ci_, si_ = np.nonzero(pmask)
-            pos[pidx[ci_, si_]] = si_
-            didx = np.asarray(dpart.idx)
-            lbc = jnp.asarray(
-                np.where(np.asarray(dpart.mask),
-                         pos[base_index[np.maximum(didx, 0)]], 0),
-                jnp.int32)
-            Xcb = partition.gather(X)
-        Xc = dpart.gather(td.Xd)
-        mask = jnp.asarray(dpart.mask)
-        # (k, nc, n_rows) gathers -> (k, n_rows, nc) class-stacked batch
-        sc = jnp.moveaxis(dpart.gather(td.S.T), -1, 1)
-        pc = jnp.moveaxis(dpart.gather(td.P.T), -1, 1)
-        cc = jnp.moveaxis(dpart.gather(td.Cvec.T), -1, 1)
-        ac = jnp.moveaxis(dpart.gather(alpha.T), -1, 1)
-        ac = jnp.where(mask[:, None, :], ac, 0.0)
-        aeqc = geqc = deqc = None
-        if td.has_equality:
-            # split the global target(s) a'u = d_g proportionally over
-            # clusters per constraint group; the pairwise/blocked sub-solver
-            # projects each gathered warm start onto its own hyperplane(s)
-            aeqc = jnp.moveaxis(dpart.gather(td.A.T), -1, 1)
-            geqc = jnp.moveaxis(dpart.gather(td.group_ids.T), -1, 1)
-            deqc = _split_eq_targets(aeqc, cc, mask, geqc,
-                                     jnp.asarray(td.Deq), td.n_groups)
+        with span(f"interlevel/level{l}/gather"):
+            # expand the base partition to dual coordinates: SVR's mirrored
+            # (alpha_i, alpha*_i) pair inherits sample i's cluster
+            dpart = partition if nd == n else Partition.build(
+                np.asarray(partition.assign)[base_index].astype(np.int32),
+                kl, partition.model)
+            Xcb = lbc = None
+            if cfg.gram_dedup and nd != n:
+                # base-indexed cluster Grams: map each dual slot to its base
+                # point's local slot inside the BASE partition's cluster
+                # (the mirrored pair shares a cluster by construction), so
+                # each cluster computes an (nb, nb) Gram instead of (2nb, 2nb)
+                pidx, pmask = (np.asarray(partition.idx),
+                               np.asarray(partition.mask))
+                pos = np.zeros(n, np.int64)
+                ci_, si_ = np.nonzero(pmask)
+                pos[pidx[ci_, si_]] = si_
+                didx = np.asarray(dpart.idx)
+                lbc = jnp.asarray(
+                    np.where(np.asarray(dpart.mask),
+                             pos[base_index[np.maximum(didx, 0)]], 0),
+                    jnp.int32)
+                Xcb = partition.gather(X)
+            Xc = dpart.gather(td.Xd)
+            mask = jnp.asarray(dpart.mask)
+            # (k, nc, n_rows) gathers -> (k, n_rows, nc) class-stacked batch
+            sc = jnp.moveaxis(dpart.gather(td.S.T), -1, 1)
+            pc = jnp.moveaxis(dpart.gather(td.P.T), -1, 1)
+            cc = jnp.moveaxis(dpart.gather(td.Cvec.T), -1, 1)
+            ac = jnp.moveaxis(dpart.gather(alpha.T), -1, 1)
+            ac = jnp.where(mask[:, None, :], ac, 0.0)
+            aeqc = geqc = deqc = None
+            if td.has_equality:
+                # split the global target(s) a'u = d_g proportionally over
+                # clusters per constraint group; the pairwise/blocked
+                # sub-solver projects each gathered warm start onto its own
+                # hyperplane(s)
+                aeqc = jnp.moveaxis(dpart.gather(td.A.T), -1, 1)
+                geqc = jnp.moveaxis(dpart.gather(td.group_ids.T), -1, 1)
+                deqc = _split_eq_targets(aeqc, cc, mask, geqc,
+                                         jnp.asarray(td.Deq), td.n_groups)
         with span(f"divide/level{l}/solve"):
             ac = _solve_clusters(cfg, Xc, sc, pc, cc, ac, mask,
                                  use_pallas=use_pallas, aeq=aeqc, geq=geqc,
@@ -554,8 +557,9 @@ def _fit_algorithm1(
             alpha.block_until_ready()
         t_train = time.perf_counter() - t0
 
-        sv_idx = np.nonzero(np.any(np.asarray(alpha) > 0, axis=0))[0]
-        sv_base = np.unique(base_index[sv_idx])
+        with span(f"interlevel/level{l}/select"):
+            sv_idx = np.nonzero(np.any(np.asarray(alpha) > 0, axis=0))[0]
+            sv_base = np.unique(base_index[sv_idx])
         st = dict(level=l, clusters=kl, cluster_time=t_cluster, train_time=t_train,
                   n_sv=int(len(sv_base)))
         stats.append(st)
@@ -574,23 +578,24 @@ def _fit_algorithm1(
         res = _solve_full(cfg, td, alpha, use_pallas=use_pallas)
         alpha = res.alpha
         alpha.block_until_ready()
-    sv_base0 = np.unique(
-        base_index[np.any(np.asarray(alpha) > 0, axis=0)])
-    st = dict(level=0, clusters=1, cluster_time=0.0,
-              train_time=time.perf_counter() - t0,
-              n_sv=int(len(sv_base0)),
-              iters=int(np.sum(np.asarray(res.iters))),
-              pg_max=float(np.max(np.asarray(res.pg_max))))
-    if res.cache_hits is not None:
-        hits = int(np.sum(np.asarray(res.cache_hits)))
-        misses = int(np.sum(np.asarray(res.cache_misses)))
-        st["cache_hits"] = hits
-        st["cache_misses"] = misses
-        st["cache_hit_rate"] = hits / max(hits + misses, 1)
-    for name in ("cache_evictions", "spills", "spill_hits"):
-        v = getattr(res, name, None)
-        if v is not None:
-            st[name] = int(np.sum(np.asarray(v)))
+    with span("interlevel/level0/select"):
+        sv_base0 = np.unique(
+            base_index[np.any(np.asarray(alpha) > 0, axis=0)])
+        st = dict(level=0, clusters=1, cluster_time=0.0,
+                  train_time=time.perf_counter() - t0,
+                  n_sv=int(len(sv_base0)),
+                  iters=int(np.sum(np.asarray(res.iters))),
+                  pg_max=float(np.max(np.asarray(res.pg_max))))
+        if res.cache_hits is not None:
+            hits = int(np.sum(np.asarray(res.cache_hits)))
+            misses = int(np.sum(np.asarray(res.cache_misses)))
+            st["cache_hits"] = hits
+            st["cache_misses"] = misses
+            st["cache_hit_rate"] = hits / max(hits + misses, 1)
+        for name in ("cache_evictions", "spills", "spill_hits"):
+            v = getattr(res, name, None)
+            if v is not None:
+                st[name] = int(np.sum(np.asarray(v)))
     if getattr(res, "trace", None) is not None:
         # the ONLY device->host trace transfer of the whole fit
         fetched = trace_fetch(res.trace)
@@ -672,28 +677,30 @@ def fit(
     SVM) ``y`` may be omitted.  ``callback(level, alpha, stats)`` fires
     after each level (level 0 = final solve) — benchmarks use it for
     time/objective curves; ``alpha`` is the task's dual vector (2n
-    coordinates for SVR).
+    coordinates for SVR).  The whole fit is the span ``fit``.
     """
-    X = jnp.asarray(X)
-    task = resolve_task(task)
-    if y is None:
-        if not task.label_free:
-            raise ValueError(f"task {task.name!r} requires labels y")
-        y = jnp.zeros(X.shape[0], X.dtype)
-    y = jnp.asarray(y, X.dtype)
-    td = task.build(X, y[None, :], cfg.C)
-    cb = None if callback is None else (lambda l, a, st: callback(l, a[0], st))
-    alpha, partition, stats, is_early = _fit_algorithm1(cfg, X, td, cb)
-    beta = td.collapse(alpha)[0]
-    rho = rho_clusters = None
-    if task.has_rho_offset:
-        rho = _recover_rho(cfg, td, task, alpha)
-        if is_early and partition is not None:
-            rho_clusters = _recover_rho_clusters(cfg, td, task, alpha,
-                                                 partition)
-    return DCSVMModel(cfg, X, y, alpha[0], partition, is_early, stats,
-                      task=task, beta=beta, rho=rho,
-                      rho_clusters=rho_clusters)
+    with span("fit"):
+        X = jnp.asarray(X)
+        task = resolve_task(task)
+        if y is None:
+            if not task.label_free:
+                raise ValueError(f"task {task.name!r} requires labels y")
+            y = jnp.zeros(X.shape[0], X.dtype)
+        y = jnp.asarray(y, X.dtype)
+        td = task.build(X, y[None, :], cfg.C)
+        cb = None if callback is None else (
+            lambda l, a, st: callback(l, a[0], st))
+        alpha, partition, stats, is_early = _fit_algorithm1(cfg, X, td, cb)
+        beta = td.collapse(alpha)[0]
+        rho = rho_clusters = None
+        if task.has_rho_offset:
+            rho = _recover_rho(cfg, td, task, alpha)
+            if is_early and partition is not None:
+                rho_clusters = _recover_rho_clusters(cfg, td, task, alpha,
+                                                     partition)
+        return DCSVMModel(cfg, X, y, alpha[0], partition, is_early, stats,
+                          task=task, beta=beta, rho=rho,
+                          rho_clusters=rho_clusters)
 
 
 def objective_value(cfg: DCSVMConfig, X: Array, y: Array, alpha: Array,

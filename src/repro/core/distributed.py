@@ -627,7 +627,8 @@ def fit_distributed(
                                           iters=cfg.kmeans_iters,
                                           sample_idx=sample_idx,
                                           balanced=True,
-                                          use_pallas=use_pallas)
+                                          use_pallas=use_pallas,
+                                          span_prefix=f"divide/level{l}")
         # expand the base partition to dual coordinates (SVR's mirrored
         # pair of a sample shares its cluster)
         dpart = part if nd == n else Partition.build(

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.kernels import HIGHEST, Kernel, f32_matmul, gram
+from repro.obs.spans import span
 
 Array = jax.Array
 
@@ -202,9 +203,17 @@ def two_step_kernel_kmeans(
     sample_idx: Optional[Array] = None,
     balanced: bool = True,
     use_pallas: bool = False,
+    *,
+    span_prefix: str,
 ) -> Partition:
     """The paper's clustering step. ``sample_idx`` overrides the random sample
-    (adaptive clustering passes the current support-vector set here)."""
+    (adaptive clustering passes the current support-vector set here).
+
+    The host part is named by spans ``<span_prefix>/fetch`` (the wait for
+    the device and the copy of the distances or the assignment),
+    ``<span_prefix>/balance`` (``balanced_assign``) and
+    ``<span_prefix>/partition`` (``Partition.build``); a fit passes
+    ``divide/level<l>``."""
     n = X.shape[0]
     m = min(m, n)
     # independent streams for the m-point sample and the kmeans init: reusing
@@ -222,7 +231,12 @@ def two_step_kernel_kmeans(
     assign, D = assign_points(kernel, model, X, use_pallas=use_pallas)
     if balanced:
         capacity = -(-n // k)  # ceil
-        assign = balanced_assign(np.asarray(D), capacity)
+        with span(f"{span_prefix}/fetch"):
+            D = np.asarray(D)
+        with span(f"{span_prefix}/balance"):
+            assign = balanced_assign(D, capacity)
     else:
-        assign = np.asarray(assign)
-    return Partition.build(np.asarray(assign, np.int32), k, model)
+        with span(f"{span_prefix}/fetch"):
+            assign = np.asarray(assign)
+    with span(f"{span_prefix}/partition"):
+        return Partition.build(np.asarray(assign, np.int32), k, model)

@@ -53,6 +53,12 @@ shed):
   loop's exception instead of hanging.  Per-batch SERVE errors still
   scatter to just the affected callers.
 
+Spans (``repro.obs.spans``, DESIGN.md §13): the batch loop's wait for work
+is ``serve/idle``; each batch is ``serve/batch`` on the loop thread
+(``serve/assemble``, ``serve/resolve``) and ``serve/compute`` on the
+executor thread (``serve/dispatch``, ``serve/sync``), both tagged with the
+batch number.
+
 ``warmup`` pre-compiles every (version, strategy, bucket) signature outside
 the request path and marks the compile-counter baseline; after that the
 engine serves with ZERO recompiles (``serve_compiles_total`` pins it).
@@ -77,6 +83,7 @@ from repro.core.predict import bucket_size
 from repro.launch.registry import ModelRegistry, RegistryEntry
 from repro.launch.serve_svm import serve_batch, serving_cache_size
 from repro.obs.metrics import MetricsRegistry
+from repro.obs.spans import span
 
 GroupKey = Tuple[str, int, str]        # (name, version, strategy)
 
@@ -133,6 +140,7 @@ class AsyncServingEngine:
         self._task: Optional[asyncio.Task] = None
         self._closed = False
         self._rid = 0
+        self._batches = 0      # served batches: the spans' ``batch`` id
         # compile accounting: everything below the mark is warmup
         self._cache_mark = serving_cache_size()
         m = self.metrics
@@ -328,7 +336,8 @@ class AsyncServingEngine:
                 if self._closed:
                     return
                 self._event.clear()
-                await self._event.wait()
+                with span("serve/idle"):
+                    await self._event.wait()
                 continue
             reqs = self._pop_ready(key)
             if not reqs:
@@ -342,50 +351,62 @@ class AsyncServingEngine:
             # the loop and surface through submit/drain/stop, never hang.
             # The popped requests are failed here; still-queued ones are
             # failed by the supervisor (_on_loop_done).
-            try:
-                entry: RegistryEntry = self.registry.resolve(key[0], key[1])
-            except BaseException as e:
-                for r in reqs:
-                    if not r.future.done():
-                        r.future.set_exception(e)
-                raise
-            try:
-                await self._serve_group(loop, entry, key, reqs)
-            except Exception as e:                 # noqa: BLE001 — scatter
-                for r in reqs:                     # failures to the callers
-                    if not r.future.done():
-                        r.future.set_exception(e)
+            self._batches += 1
+            with span("serve/batch", batch=self._batches):
+                try:
+                    entry: RegistryEntry = self.registry.resolve(key[0],
+                                                                 key[1])
+                except BaseException as e:
+                    for r in reqs:
+                        if not r.future.done():
+                            r.future.set_exception(e)
+                    raise
+                try:
+                    await self._serve_group(loop, entry, key, reqs,
+                                            self._batches)
+                except Exception as e:             # noqa: BLE001 — scatter
+                    for r in reqs:                 # failures to the callers
+                        if not r.future.done():
+                            r.future.set_exception(e)
             self.metrics.gauge("serve_queue_depth").set(self._depth())
             self._served.set()
 
     async def _serve_group(self, loop: asyncio.AbstractEventLoop,
                            entry: RegistryEntry, key: GroupKey,
-                           reqs: Sequence[_Request]) -> None:
+                           reqs: Sequence[_Request], batch: int) -> None:
+        """Serve one popped batch: ``serve/assemble`` and ``serve/resolve``
+        on the loop thread, ``serve/compute`` (``serve/dispatch``,
+        ``serve/sync``) on the executor thread, all under ``batch``."""
         name, version, strategy = key
         nq = sum(r.nq for r in reqs)
         bucket = bucket_size(nq, lo=self.config.min_bucket,
                              hi=self.config.max_bucket)
-        # one host alloc at exactly the bucket shape: serve_batch sees a
-        # full-bucket batch (pad path untouched), so every eager op inside
-        # it runs at a warmup-covered signature — no hidden compiles for
-        # ragged sizes, on top of the jitted scorers' bucket signatures
-        X = np.zeros((bucket, reqs[0].X.shape[1]), reqs[0].X.dtype)
-        off = 0
-        for r in reqs:
-            X[off: off + r.nq] = r.X
-            off += r.nq
+        with span("serve/assemble"):
+            # one host alloc at exactly the bucket shape: serve_batch sees a
+            # full-bucket batch (pad path untouched), so every eager op
+            # inside it runs at a warmup-covered signature — no hidden
+            # compiles for ragged sizes, on top of the jitted scorers'
+            # bucket signatures
+            X = np.zeros((bucket, reqs[0].X.shape[1]), reqs[0].X.dtype)
+            off = 0
+            for r in reqs:
+                X[off: off + r.nq] = r.X
+                off += r.nq
 
         def compute():
-            # one H2D transfer of the full bucket (jnp.asarray, not raw
-            # numpy: the jit fast path keys numpy args separately, which
-            # would double every warmed signature)
-            pred, scores = serve_batch(entry.sm, jnp.asarray(X), entry.kern,
-                                       strategy,
-                                       use_pallas=self.config.use_pallas,
-                                       bucket=bucket)
-            # device->host once, in the executor thread (this is also the
-            # device sync); scatter below is then pure numpy slicing
-            return np.asarray(pred)[:nq], np.asarray(scores)[:nq]
+            with span("serve/compute", batch=batch):
+                with span("serve/dispatch"):
+                    # one H2D transfer of the full bucket (jnp.asarray, not
+                    # raw numpy: the jit fast path keys numpy args
+                    # separately, which would double every warmed signature)
+                    pred, scores = serve_batch(
+                        entry.sm, jnp.asarray(X), entry.kern, strategy,
+                        use_pallas=self.config.use_pallas, bucket=bucket)
+                with span("serve/sync"):
+                    # device->host once, in the executor thread (this is
+                    # also the device sync); scatter below is then pure
+                    # numpy slicing
+                    return np.asarray(pred)[:nq], np.asarray(scores)[:nq]
 
         # the device sync runs OFF the event loop so submits, deadline
         # timers, and drain wakeups keep firing during the batch
@@ -396,38 +417,40 @@ class AsyncServingEngine:
             self._inflight[key] -= len(reqs)
             if not self._inflight[key]:
                 del self._inflight[key]
-        t_done = time.perf_counter()
-
-        m = self.metrics
-        ver = str(version)
-        m.histogram("serve_batch_fill_ratio").observe(nq / bucket)
-        m.histogram("serve_compute_seconds").observe(t_done - reqs[0].t_pop)
-        hist = m.histogram("serve_latency_seconds", model=name, version=ver,
-                           strategy=strategy)
-        wait_h = m.histogram("serve_queue_wait_seconds", lo=1e-6)
-        cache = serving_cache_size()
-        if cache > self._cache_mark:
-            m.counter("serve_compiles_total").inc(cache - self._cache_mark)
-            self._cache_mark = cache
-        # only DELIVERED requests are counted and observed: a request
-        # cancelled mid-compute neither lands in the histograms (no p99
-        # skew) nor in the request/query counters
-        delivered = d_rows = 0
-        off = 0
-        for r in reqs:
-            if not r.future.done():
-                r.future.set_result(
-                    (pred[off: off + r.nq], scores[off: off + r.nq]))
-                hist.observe(t_done - r.t_enq)
-                wait_h.observe(r.t_pop - r.t_enq)
-                delivered += 1
-                d_rows += r.nq
-            off += r.nq
-        if delivered:
-            m.counter("serve_requests_total", model=name, version=ver,
-                      strategy=strategy).inc(delivered)
-            m.counter("serve_queries_total", model=name, version=ver,
-                      strategy=strategy).inc(d_rows)
+        with span("serve/resolve"):
+            t_done = time.perf_counter()
+            m = self.metrics
+            ver = str(version)
+            m.histogram("serve_batch_fill_ratio").observe(nq / bucket)
+            m.histogram("serve_compute_seconds").observe(
+                t_done - reqs[0].t_pop)
+            hist = m.histogram("serve_latency_seconds", model=name,
+                               version=ver, strategy=strategy)
+            wait_h = m.histogram("serve_queue_wait_seconds", lo=1e-6)
+            cache = serving_cache_size()
+            if cache > self._cache_mark:
+                m.counter("serve_compiles_total").inc(
+                    cache - self._cache_mark)
+                self._cache_mark = cache
+            # only DELIVERED requests are counted and observed: a request
+            # cancelled mid-compute neither lands in the histograms (no p99
+            # skew) nor in the request/query counters
+            delivered = d_rows = 0
+            off = 0
+            for r in reqs:
+                if not r.future.done():
+                    r.future.set_result(
+                        (pred[off: off + r.nq], scores[off: off + r.nq]))
+                    hist.observe(t_done - r.t_enq)
+                    wait_h.observe(r.t_pop - r.t_enq)
+                    delivered += 1
+                    d_rows += r.nq
+                off += r.nq
+            if delivered:
+                m.counter("serve_requests_total", model=name, version=ver,
+                          strategy=strategy).inc(delivered)
+                m.counter("serve_queries_total", model=name, version=ver,
+                          strategy=strategy).inc(d_rows)
 
     # -- warmup ----------------------------------------------------------
     def warmup(self, name: Optional[str] = None,

@@ -6,8 +6,9 @@ Three cooperating pieces (DESIGN.md §13):
 - ``obs.trace``   — ``ConvTrace``, a jit-safe ring buffer pytree that solver
   while-loops write per-iteration samples into; fetched once at fit exit.
 - ``obs.spans``   — ``span(name)`` context manager building a wall-clock span
-  tree over fit phases, mirrored into ``jax.profiler.TraceAnnotation`` so
-  XLA/Perfetto profiles carry the same names; exports Chrome trace JSON.
+  tree (one per thread) over fit and serving-engine phases, mirrored into
+  ``jax.profiler.TraceAnnotation`` so XLA/Perfetto profiles carry the same
+  names; collector pauses show as ``host/gc``; exports Chrome trace JSON.
 - ``obs.metrics`` — streaming log-bucket latency histograms + labeled
   counters with Prometheus-text and JSON exposition for the serving loop.
 """

@@ -4,60 +4,98 @@
 ``jax.profiler.TraceAnnotation`` with the same name, so when the user runs
 the XLA profiler the device timeline carries the identical labels as our
 host-side tree — that naming contract is the whole point (DESIGN.md §13).
+``span("serve/compute", batch=7)`` adds identifiers: they ride on the
+annotation as stats (its event name stays the bare span name) and on the
+tracer's record.
 
 Host-side recording only happens while a ``SpanTracer`` is activated
 (``with tracer.activate(): fit(...)``); otherwise ``span`` costs one
 TraceAnnotation enter/exit, which is a no-op when no profiler session is
-running.  The tracer exports Chrome trace-event JSON (complete ``X``
-events, microsecond timestamps — loadable in Perfetto / chrome://tracing)
-and an aggregated text summary table.
+running.  The open span lives in a context variable, so every thread and
+every asyncio task nests its own spans: the serving engine's event loop
+(spans held open across ``await``) and its executor build correct trees;
+every span records its thread.  Spans are timed on the monotonic
+``perf_counter_ns``; the tracer takes the wall clock's offset once, so its
+Chrome trace-event JSON (complete ``X`` events, microsecond timestamps,
+one ``tid`` per thread — loadable in Perfetto / chrome://tracing) lies on
+the timeline of the profiler's host tracer, which stamps ``time.time_ns()``.
+The tracer also prints an aggregated text summary table.
+
+Collector pauses: a ``gc.callbacks`` hook, installed once at import, wraps
+every collection in a ``host/gc`` annotation, and while a tracer is active
+appends ``(start_ns, end_ns, generation, thread)`` to ``SpanTracer.gc``
+(outside the span tree); the Chrome export shows them as ``host/gc``
+events on their threads.
 """
 from __future__ import annotations
 
+import gc
 import json
+import threading
 import time
 from contextlib import contextmanager
+from contextvars import ContextVar
 from dataclasses import dataclass, field
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import jax
 
-# Module-global active tracer; spans record into it when set.  Single
-# host thread drives fits here, so a plain global (not a contextvar) is
-# enough and keeps the hot path one attribute load.
+# Module-global active tracer; spans on every thread record into it when
+# set (each thread and task nesting its own, ``_OPEN``).  A plain global
+# keeps the hot path one attribute load.
 _ACTIVE: Optional["SpanTracer"] = None
+
+GC_SPAN = "host/gc"
+
+# The innermost open span of this thread or asyncio task, with its tracer.
+# Every task runs in a copy of its creator's context and every thread
+# starts empty, so a span held open across ``await`` parents only what its
+# own task opens.
+_OPEN: ContextVar[Optional[Tuple["SpanTracer", "Span"]]] = ContextVar(
+    "repro_open_span", default=None)
 
 
 @dataclass
 class Span:
     name: str
-    t0: float
-    t1: Optional[float] = None
+    t0: int                               # time.perf_counter_ns()
+    t1: Optional[int] = None
     children: List["Span"] = field(default_factory=list)
+    ids: Dict[str, Any] = field(default_factory=dict)
+    thread: int = 0                       # threading.get_native_id()
 
     @property
     def duration(self) -> float:
-        return (self.t1 if self.t1 is not None else time.perf_counter()) - self.t0
+        """Seconds."""
+        return ((self.t1 if self.t1 is not None else time.perf_counter_ns())
+                - self.t0) * 1e-9
 
 
 class SpanTracer:
     """Collects a tree of wall-clock spans for one fit/serve run."""
 
     def __init__(self) -> None:
-        self.origin = time.perf_counter()
         self.roots: List[Span] = []
-        self._stack: List[Span] = []
+        # collector pauses while active: (start_ns, end_ns, generation, tid)
+        self.gc: List[Tuple[int, int, int, int]] = []
+        # perf_counter_ns -> time.time_ns(), for the exports
+        self._wall_offset_ns = time.time_ns() - time.perf_counter_ns()
 
     @contextmanager
-    def span(self, name: str) -> Iterator[Span]:
-        s = Span(name=name, t0=time.perf_counter())
-        (self._stack[-1].children if self._stack else self.roots).append(s)
-        self._stack.append(s)
+    def span(self, name: str, **ids: Any) -> Iterator[Span]:
+        open_ = _OPEN.get()
+        s = Span(name=name, t0=time.perf_counter_ns(), ids=ids,
+                 thread=threading.get_native_id())
+        if open_ is not None and open_[0] is self:
+            open_[1].children.append(s)
+        else:
+            self.roots.append(s)
+        token = _OPEN.set((self, s))
         try:
             yield s
         finally:
-            s.t1 = time.perf_counter()
-            self._stack.pop()
+            s.t1 = time.perf_counter_ns()
+            _OPEN.reset(token)
 
     @contextmanager
     def activate(self) -> Iterator["SpanTracer"]:
@@ -78,17 +116,22 @@ class SpanTracer:
             stack.extend((c, depth + 1) for c in reversed(s.children))
 
     def chrome_trace(self) -> Dict[str, Any]:
-        """Chrome trace-event JSON: complete ``X`` events, ts/dur in µs."""
+        """Chrome trace-event JSON: complete ``X`` events, ts/dur in µs of
+        the wall clock (the profiler's), one ``tid`` per thread; collector
+        pauses are ``host/gc`` events with their generation."""
+        off = self._wall_offset_ns
         events = []
         for s, _ in self._walk():
-            events.append({
-                "name": s.name,
-                "ph": "X",
-                "ts": (s.t0 - self.origin) * 1e6,
-                "dur": max(s.duration, 0.0) * 1e6,
-                "pid": 0,
-                "tid": 0,
-            })
+            ev = {"name": s.name, "ph": "X", "ts": (s.t0 + off) / 1e3,
+                  "dur": max(s.duration, 0.0) * 1e6, "pid": 0,
+                  "tid": s.thread}
+            if s.ids:
+                ev["args"] = dict(s.ids)
+            events.append(ev)
+        for t0, t1, gen, tid in self.gc:
+            events.append({"name": GC_SPAN, "ph": "X", "ts": (t0 + off) / 1e3,
+                           "dur": (t1 - t0) / 1e3, "pid": 0, "tid": tid,
+                           "args": {"generation": gen}})
         events.sort(key=lambda e: e["ts"])
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
@@ -115,13 +158,42 @@ class SpanTracer:
 
 
 @contextmanager
-def span(name: str) -> Iterator[None]:
-    """Name a fit phase: host span tree (when a tracer is active) + device
-    profiler annotation (always — free unless a profiler session runs)."""
+def span(name: str, **ids: Any) -> Iterator[None]:
+    """Name a program phase: host span tree (when a tracer is active) +
+    profiler annotation (always — free unless a profiler session runs).
+    ``ids`` (e.g. ``batch=7``) go to both as identifiers."""
     tracer = _ACTIVE
-    with jax.profiler.TraceAnnotation(name):
+    with jax.profiler.TraceAnnotation(name, **ids):
         if tracer is None:
             yield
         else:
-            with tracer.span(name):
+            with tracer.span(name, **ids):
                 yield
+
+
+# The collection in progress: (annotation, start_ns or None).  Collections
+# never overlap (the interpreter runs one at a time, start and stop on the
+# collecting thread), so one slot is enough.
+_gc_open: List[Any] = [None, None]
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    if phase == "start":
+        a = jax.profiler.TraceAnnotation(GC_SPAN)
+        a.__enter__()
+        _gc_open[0] = a
+        _gc_open[1] = time.perf_counter_ns() if _ACTIVE is not None else None
+        return
+    a, t0 = _gc_open
+    if a is None:
+        return
+    _gc_open[0] = _gc_open[1] = None
+    a.__exit__(None, None, None)
+    tracer = _ACTIVE
+    if tracer is not None and t0 is not None:
+        tracer.gc.append((t0, time.perf_counter_ns(), int(info["generation"]),
+                          threading.get_native_id()))
+
+
+if _on_gc not in gc.callbacks:
+    gc.callbacks.append(_on_gc)

@@ -204,3 +204,49 @@ def test_objective_value_matches_dense():
     f_dense = float(0.5 * a @ Q @ a - a.sum())
     f_chunk = float(objective_value(cfg, Xtr, ytr, a))
     assert abs(f_dense - f_chunk) < 1e-3 * (1 + abs(f_dense))
+
+
+def _span_names(tracer):
+    out, stack = set(), list(tracer.roots)
+    while stack:
+        s = stack.pop()
+        out.add(s.name)
+        stack.extend(s.children)
+    return out
+
+
+@pytest.mark.parametrize("early", [0, 1])
+def test_fit_spans_name_the_host_work(early):
+    """A tiny exact and a tiny early fit record every fit span: the root
+    ``fit``; per level the divide's cluster (with its fetch, balance and
+    partition), the gathers and the cluster solve, and the SV selection;
+    level 0's conquer and selection.  No span time is left unexplained:
+    ``fit``'s own time, outside its phase spans, is under 5% of it (a warm
+    fit: the first one compiles outside any span)."""
+    from repro.obs.spans import SpanTracer
+
+    Xtr, ytr, _, _ = _dataset(4000, key=45)
+    cfg = DCSVMConfig(kernel=KERN, C=4.0, k=4, levels=2, m=200, tol=1e-3,
+                      early_stop_level=early)
+    fit(cfg, Xtr, ytr)
+    tracer = SpanTracer()
+    with tracer.activate():
+        fit(cfg, Xtr, ytr)
+    want = {"fit"}
+    for l in (2, 1):
+        want |= {f"divide/level{l}/{p}"
+                 for p in ("cluster", "fetch", "balance", "partition",
+                           "solve")}
+        want |= {f"interlevel/level{l}/gather", f"interlevel/level{l}/select"}
+    if not early:
+        want |= {"conquer/refine", "conquer/solve", "interlevel/level0/select"}
+    assert _span_names(tracer) == want
+    (root,) = tracer.roots
+    assert root.name == "fit"
+    for c in root.children:
+        if c.name.endswith("/cluster"):
+            level = c.name.rsplit("/", 1)[0]
+            assert [k.name for k in c.children] == [
+                f"{level}/fetch", f"{level}/balance", f"{level}/partition"]
+    own = root.duration - sum(c.duration for c in root.children)
+    assert 0 <= own < 0.05 * root.duration, (own, root.duration)

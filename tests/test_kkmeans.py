@@ -38,7 +38,7 @@ def test_two_step_assignment_matches_full_on_sample():
     X, y = gaussian_mixture(jax.random.PRNGKey(2), 600, d=6, modes_per_class=3)
     kern = Kernel("rbf", gamma=4.0)
     part = two_step_kernel_kmeans(kern, X, k=6, key=jax.random.PRNGKey(3), m=200,
-                                  balanced=False)
+                                  balanced=False, span_prefix="divide/level1")
     # routing model assigns consistently with the stored partition
     a2, _ = assign_points(kern, part.model, X)
     assert (np.asarray(a2) == part.assign).mean() > 0.999
@@ -62,7 +62,8 @@ def test_balanced_assign_prefers_near_centers():
 def test_partition_gather_scatter_roundtrip():
     X, _ = gaussian_mixture(jax.random.PRNGKey(4), 300, d=4)
     kern = Kernel("rbf", gamma=2.0)
-    part = two_step_kernel_kmeans(kern, X, k=5, key=jax.random.PRNGKey(5), m=100)
+    part = two_step_kernel_kmeans(kern, X, k=5, key=jax.random.PRNGKey(5), m=100,
+                                  span_prefix="divide/level1")
     v = jnp.arange(300, dtype=jnp.float32)
     vc = jnp.where(jnp.asarray(part.mask), part.gather(v), 0.0)
     back = part.scatter(vc, 300)
@@ -75,7 +76,8 @@ def test_kkmeans_partition_beats_random_on_dpi():
     X, _ = gaussian_mixture(jax.random.PRNGKey(6), 800, d=8, modes_per_class=4,
                             spread=0.08)
     kern = Kernel("rbf", gamma=16.0)
-    part = two_step_kernel_kmeans(kern, X, k=8, key=jax.random.PRNGKey(7), m=300)
+    part = two_step_kernel_kmeans(kern, X, k=8, key=jax.random.PRNGKey(7), m=300,
+                                  span_prefix="divide/level1")
     d_kk = float(d_pi(kern, X, jnp.asarray(part.assign)))
     rng = np.random.default_rng(0)
     rand_assign = rng.integers(0, 8, size=800)
@@ -88,7 +90,8 @@ def test_empty_cluster_reseeding():
     X = jnp.concatenate([jnp.zeros((50, 2)), jnp.ones((50, 2))], 0)
     X = X + 0.01 * jax.random.normal(jax.random.PRNGKey(8), X.shape)
     kern = Kernel("rbf", gamma=1.0)
-    part = two_step_kernel_kmeans(kern, X, k=4, key=jax.random.PRNGKey(9), m=100)
+    part = two_step_kernel_kmeans(kern, X, k=4, key=jax.random.PRNGKey(9), m=100,
+                                  span_prefix="divide/level1")
     counts = np.bincount(part.assign, minlength=4)
     assert (counts > 0).all()
 
@@ -145,7 +148,8 @@ def test_two_step_splits_sample_and_init_keys():
     X, _ = gaussian_mixture(jax.random.PRNGKey(30), 300, d=4)
     key = jax.random.PRNGKey(42)
     part = two_step_kernel_kmeans(Kernel("rbf", gamma=2.0), X, k=3, key=key,
-                                  m=64, iters=2, balanced=False)
+                                  m=64, iters=2, balanced=False,
+                                  span_prefix="divide/level1")
     key_sample, _ = jax.random.split(key)
     expected = X[jax.random.choice(key_sample, 300, shape=(64,), replace=False)]
     np.testing.assert_array_equal(np.asarray(part.model.Xm),

@@ -12,8 +12,12 @@ The load-bearing guarantees pinned here:
 * Chrome trace exports are schema-valid (complete ``X`` events, sorted,
   non-negative durations); histograms/registries expose Prometheus text.
 """
+import gc
+import glob
 import json
 import math
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -201,6 +205,168 @@ def test_span_nesting_restores_active_tracer():
     assert {s.name for s in t1.roots} == {"outer"}
     assert {s.name for s in t2.roots} == {"inner"}
     assert [c.name for c in t1.roots[0].children] == ["outer2"]
+
+
+def test_span_threads_build_separate_trees():
+    """Two threads open nested spans at the same time under one tracer: the
+    barrier makes each open and close while the other holds its span open,
+    so a shared stack would pop the wrong span.  Each gets its own tree."""
+    tracer = SpanTracer()
+    step = threading.Barrier(2, timeout=10)
+    tids = {}
+
+    def worker(tag):
+        tids[tag] = threading.get_native_id()
+        with span(f"outer/{tag}"):
+            step.wait()
+            with span(f"inner/{tag}", batch=tag):
+                step.wait()
+            step.wait()
+            with span(f"second/{tag}"):
+                step.wait()
+
+    with tracer.activate():
+        ths = [threading.Thread(target=worker, args=(t,)) for t in "ab"]
+        for th in ths:
+            th.start()
+        for th in ths:
+            th.join(timeout=30)
+    assert not any(th.is_alive() for th in ths)
+    roots = {r.name: r for r in tracer.roots}
+    assert set(roots) == {"outer/a", "outer/b"}
+    for tag in "ab":
+        r = roots[f"outer/{tag}"]
+        assert [c.name for c in r.children] == [f"inner/{tag}",
+                                                f"second/{tag}"]
+        assert r.children[0].ids == {"batch": tag}
+        assert {r.thread} | {c.thread for c in r.children} == {tids[tag]}
+        assert all(r.t0 <= c.t0 <= c.t1 <= r.t1 for c in r.children)
+    assert tids["a"] != tids["b"]
+
+
+def test_span_tasks_nest_across_await():
+    """Two asyncio tasks on one thread hold spans open across ``await`` and
+    interleave: ``outer/a`` closes while ``outer/b`` is open, and each
+    child nests under its own task's span."""
+    import asyncio
+
+    tracer = SpanTracer()
+
+    async def main():
+        a_open, b_open, a_done = (asyncio.Event() for _ in range(3))
+
+        async def a():
+            with span("outer/a"):
+                a_open.set()
+                await b_open.wait()
+                with span("inner/a"):
+                    pass
+            a_done.set()
+
+        async def b():
+            await a_open.wait()
+            with span("outer/b"):
+                b_open.set()
+                await a_done.wait()
+                with span("inner/b"):
+                    pass
+
+        await asyncio.gather(a(), b())
+
+    with tracer.activate():
+        asyncio.run(main())
+        with span("after"):
+            pass
+    assert [r.name for r in tracer.roots] == ["outer/a", "outer/b", "after"]
+    for r, tag in zip(tracer.roots, "ab"):
+        assert [c.name for c in r.children] == [f"inner/{tag}"]
+    assert tracer.roots[2].children == []
+
+
+def test_gc_pause_recorded_outside_the_tree():
+    tracer = SpanTracer()
+    gc.collect()                                    # not active: not kept
+    with tracer.activate():
+        with span("work"):
+            gc.collect()
+    assert [r.name for r in tracer.roots] == ["work"]
+    assert tracer.roots[0].children == []
+    full = [g for g in tracer.gc if g[2] == 2]
+    assert full, tracer.gc
+    t0, t1, _, tid = full[-1]
+    w = tracer.roots[0]
+    assert w.t0 <= t0 <= t1 <= w.t1
+    assert tid == threading.get_native_id()
+    n = len(tracer.gc)
+    gc.collect()
+    assert len(tracer.gc) == n
+    # the Chrome export shows each pause on its thread, inside ``work``
+    ev = tracer.chrome_trace()["traceEvents"]
+    pauses = [e for e in ev if e["name"] == "host/gc"]
+    assert len(pauses) == n
+    p = [e for e in pauses if e["args"] == {"generation": 2}][-1]
+    (wev,) = [e for e in ev if e["name"] == "work"]
+    assert p["tid"] == tid
+    assert wev["ts"] <= p["ts"] <= p["ts"] + p["dur"] <= wev["ts"] + wev["dur"]
+    assert p["dur"] == pytest.approx((t1 - t0) / 1e3)
+
+
+def test_span_ids_kept():
+    tracer = SpanTracer()
+    with tracer.activate():
+        with span("serve/batch", batch=3):
+            with span("serve/assemble"):
+                pass
+    b = tracer.roots[0]
+    assert b.ids == {"batch": 3} and b.children[0].ids == {}
+    ev = {e["name"]: e for e in tracer.chrome_trace()["traceEvents"]}
+    assert ev["serve/batch"]["args"] == {"batch": 3}
+    assert "args" not in ev["serve/assemble"]
+    assert ev["serve/batch"]["tid"] == threading.get_native_id()
+
+
+def test_span_clock_agrees_with_the_profiler(tmp_path):
+    """The tracer and the profiler's host tracer stamp the same spans: the
+    distance between two span starts agrees within 0.2 ms, and an
+    identifier rides on the annotation as a stat while the event keeps the
+    bare name."""
+    from jax.profiler import ProfileData
+
+    tracer = SpanTracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracer.activate():
+            wall0 = time.time_ns()
+            with span("clock/first", batch=11):
+                time.sleep(0.01)
+            time.sleep(0.02)
+            with span("clock/second"):
+                time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = ProfileData.from_file(path[0])
+    ev = {}
+    for plane in pd.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("clock/"):
+                        ev[e.name] = e
+    assert set(ev) == {"clock/first", "clock/second"}
+    assert dict(ev["clock/first"].stats).get("batch") == 11
+    a, b = tracer.roots
+    d_tracer = (b.t0 - a.t0) * 1e-9
+    d_profiler = (ev["clock/second"].start_ns - ev["clock/first"].start_ns) \
+        * 1e-9
+    assert d_tracer > 0.02
+    assert abs(d_tracer - d_profiler) < 0.2e-3
+    assert abs(a.duration - ev["clock/first"].duration_ns * 1e-9) < 0.2e-3
+    # the export stamps the wall clock the profiler's host tracer uses
+    # (ProfileData's starts are relative to the session, so the check is
+    # against time.time_ns() read just before the first span)
+    ts = {e["name"]: e["ts"] for e in tracer.chrome_trace()["traceEvents"]}
+    assert abs(ts["clock/first"] * 1e3 - wall0) < 1e6
 
 
 # ---------------------------------------------------------------------------

@@ -325,6 +325,53 @@ def test_engine_submit_requires_running_loop(registry2):
         asyncio.run(engine.submit(np.zeros((2, 8), np.float32), "mix"))
 
 
+def test_engine_spans_per_batch(registry2):
+    """Under a SpanTracer each served batch gives one ``serve/batch`` on the
+    loop thread (children ``serve/assemble``, ``serve/resolve``) and one
+    ``serve/compute`` on the executor thread (children ``serve/dispatch``,
+    ``serve/sync``) with the same batch id; waits for work are
+    ``serve/idle``."""
+    from repro.obs.spans import SpanTracer
+
+    Xpool = np.asarray(registry2.resolve("mix").sm.Xall)
+    reqs = _mixed_batches(Xpool, [3, 17, 40, 8, 1, 30], seed=11)
+    engine = AsyncServingEngine(registry2, EngineConfig(max_batch=64))
+    engine.warmup("mix", strategies=["exact"])
+    tracer = SpanTracer()
+
+    async def main():
+        async with engine:
+            for i in range(0, len(reqs), 2):       # bursts of two, then idle
+                await asyncio.gather(*[engine.submit(r, "mix",
+                                                     strategy="exact")
+                                       for r in reqs[i:i + 2]])
+                await asyncio.sleep(0.01)
+
+    with tracer.activate():
+        asyncio.run(main())
+    loop_tid = threading.get_native_id()       # asyncio.run's loop thread
+    roots = tracer.roots
+    batches = [r for r in roots if r.name == "serve/batch"]
+    computes = {r.ids["batch"]: r for r in roots if r.name == "serve/compute"}
+    assert {r.name for r in roots} == {"serve/batch", "serve/compute",
+                                       "serve/idle"}
+    n = engine.metrics.histogram("serve_batch_fill_ratio").total
+    assert len(batches) == n == len(computes) >= 3
+    assert sorted(b.ids["batch"] for b in batches) == sorted(computes)
+    for b in batches:
+        assert b.thread == loop_tid
+        assert [c.name for c in b.children] == ["serve/assemble",
+                                                "serve/resolve"]
+        c = computes[b.ids["batch"]]
+        assert c.thread != loop_tid
+        assert [k.name for k in c.children] == ["serve/dispatch",
+                                                "serve/sync"]
+        assert b.t0 <= c.t0 <= c.t1 <= b.t1
+    idle = [r for r in roots if r.name == "serve/idle"]
+    assert idle and all(r.thread == loop_tid and not r.children
+                        for r in idle)
+
+
 # ---------------------------------------------------------------------------
 # overload robustness: shed / deadlines / liveness / supervision
 # ---------------------------------------------------------------------------
