@@ -561,7 +561,8 @@ def _fit_algorithm1(
             sv_idx = np.nonzero(np.any(np.asarray(alpha) > 0, axis=0))[0]
             sv_base = np.unique(base_index[sv_idx])
         st = dict(level=l, clusters=kl, cluster_time=t_cluster, train_time=t_train,
-                  n_sv=int(len(sv_base)))
+                  n_sv=int(len(sv_base)),
+                  balance_redirected=partition.redirected)
         stats.append(st)
         if callback is not None:
             callback(l, alpha, st)

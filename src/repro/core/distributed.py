@@ -645,7 +645,8 @@ def fit_distributed(
         # point (the box family keeps alpha >= 0, so mass > 0 <=> any SV)
         sv_base = jnp.zeros(n, X.dtype).at[bidx].add(alpha)
         stats.append(dict(level=l, clusters=kl,
-                          n_sv=jnp.sum(sv_base > 0)))
+                          n_sv=jnp.sum(sv_base > 0),
+                          balance_redirected=part.redirected))
 
     trace_cap = getattr(cfg, "trace", None) or 0
     ccfg = ConquerConfig(kernel=cfg.kernel, C=cfg.C, tol=cfg.tol,

@@ -15,8 +15,11 @@ used at serving time by early prediction (paper eq. 11).
 
 Balanced partitioning: SPMD shards must be equal-sized, and the paper itself
 prefers balanced partitions (Sec. 3).  ``balanced_assign`` does a greedy
-capacity-constrained assignment ordered by assignment confidence (host-side
-numpy: partitioning is one-off data preparation, not a jitted hot path).
+capacity-constrained assignment ordered by assignment confidence.  It runs
+once per level of every fit, on the fit's critical path while the device
+waits: the row reductions (nearest centre, confidence) run on the device
+that made the distances, and the greedy walks the points in vectorised
+NumPy blocks over the fetched distances, not one at a time.
 """
 from __future__ import annotations
 
@@ -118,35 +121,86 @@ def route(kernel: Kernel, model: KKMeansModel, X: Array) -> Array:
     return assign_points(kernel, model, X)[0]
 
 
+@jax.jit
+def _nearest_two(D: Array) -> Tuple[Array, Array, Array]:
+    """Per row of the (n, k) distances: the nearest centre (the lowest index
+    among ties), its distance, and the second-smallest distance (the row
+    minimum with the nearest masked; +inf when k = 1).  These are the only
+    full passes over D, so they run where D is made, on the device."""
+    first = jnp.argmin(D, axis=1)
+    nearest = jnp.arange(D.shape[1])[None, :] == first[:, None]
+    d0 = jnp.min(D, axis=1)
+    d1 = jnp.min(jnp.where(nearest, jnp.inf, D), axis=1)
+    return first, d0, d1
+
+
+def _balance(D: np.ndarray, capacity: int,
+             nearest: Tuple[Array, Array, Array]) -> Tuple[np.ndarray, int, int]:
+    """``balanced_assign`` with its counters: ``(assign, redirected, steps)``,
+    the points not at their nearest centre and the greedy's block steps.
+    ``nearest`` is ``_nearest_two`` of the same distances."""
+    n, k = D.shape
+    if n > k * capacity:
+        raise ValueError(f"capacity {capacity} x {k} clusters < n={n}")
+    # blocks of rows are gathered below; a TPU hands an (n, k < 128) array
+    # over column-major, where each gathered row is a strided read
+    D = np.ascontiguousarray(D)
+    first, d0, d1 = jax.device_get(nearest)
+    gap = d1.astype(np.float64) - d0.astype(np.float64)
+    order = np.argsort(-gap, kind="stable")    # big gap first, ties by index
+    rows = np.arange(n)
+
+    # The greedy over blocks of B ordered points.  A block's points take
+    # their nearest open centre; ranking each among the block's points that
+    # chose the same centre finds the first point that would overfill its
+    # centre.  The points before it get what the one-at-a-time rule gives
+    # them (a centre that fills inside the block matters only to the points
+    # that chose it), so the step accepts them, shuts the full centres and
+    # resumes at that point.  Each step accepts a point and each that stops
+    # early shuts a centre: at most about n / B + k steps.
+    room = np.full(k, capacity, np.int64)
+    shut = np.zeros(k, D.dtype)                # +inf on full centres
+    out = np.empty(n, np.int32)
+    B = -(-n // k)
+    buf = np.empty((B, k), D.dtype)
+    pos = steps = 0
+    while pos < n:
+        steps += 1
+        blk = order[pos:pos + B]
+        b = len(blk)
+        sub = np.take(D, blk, axis=0, out=buf[:b])
+        sub += shut
+        choice = np.argmin(sub, axis=1)
+        # a row whose open centres are all +inf (centres no sampled point
+        # reached, ``assign_points``) takes the lowest open one, as a stable
+        # sort of its row would; argmin may have picked a shut one
+        choice[np.isinf(sub[rows[:b], choice])] = np.argmax(room > 0)
+        srt = np.argsort(choice, kind="stable")
+        cs = choice[srt]
+        starts = np.concatenate(([True], cs[1:] != cs[:-1]))
+        rank = rows[:b] - np.maximum.accumulate(np.where(starts, rows[:b], 0))
+        over = srt[rank >= room[cs]]
+        m = int(over.min()) if len(over) else b
+        out[blk[:m]] = choice[:m]
+        room -= np.bincount(choice[:m], minlength=k)
+        shut[room == 0] = np.inf
+        pos += m
+    return out, int(np.count_nonzero(out != first)), steps
+
+
 def balanced_assign(D: np.ndarray, capacity: int) -> np.ndarray:
     """Greedy capacity-constrained assignment from an (n, k) distance matrix.
 
     Points are processed in order of confidence (gap between best and
-    second-best center); each takes its nearest center that still has room.
-    Guarantees every cluster gets at most ``capacity`` points; with
-    n <= k * capacity every point is assigned.
+    second-best center, computed in float64; ties by lowest index); each
+    takes its nearest center that still has room (ties by lowest index, so a
+    +inf column — an empty center — fills only after every finite one of the
+    point's row is full).  Guarantees every cluster gets at most
+    ``capacity`` points; with n <= k * capacity every point is assigned.
+    ``D`` is taken in float32, as ``assign_points`` makes it.
     """
-    D = np.asarray(D, dtype=np.float64)
-    n, k = D.shape
-    if n > k * capacity:
-        raise ValueError(f"capacity {capacity} x {k} clusters < n={n}")
-    order_pref = np.argsort(D, axis=1)                 # per-point center ranking
-    if k > 1:
-        part = np.partition(D, 1, axis=1)
-        confidence = part[:, 1] - part[:, 0]           # big gap = assign first
-    else:
-        confidence = np.zeros(n)
-    point_order = np.argsort(-confidence)
-    remaining = np.full(k, capacity, dtype=np.int64)
-    out = np.full(n, -1, dtype=np.int32)
-    for i in point_order:
-        for c in order_pref[i]:
-            if remaining[c] > 0:
-                out[i] = c
-                remaining[c] -= 1
-                break
-    assert (out >= 0).all()
-    return out
+    D = np.asarray(D, np.float32)
+    return _balance(D, capacity, _nearest_two(D))[0]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -166,9 +220,11 @@ class Partition:
     k: int
     nc: int                 # slots per cluster (k * nc >= n)
     model: KKMeansModel     # routing model (implicit centers)
+    redirected: int = 0     # points the balance moved off their nearest center
 
     @staticmethod
-    def build(assign: np.ndarray, k: int, model: KKMeansModel) -> "Partition":
+    def build(assign: np.ndarray, k: int, model: KKMeansModel,
+              redirected: int = 0) -> "Partition":
         n = assign.shape[0]
         counts = np.bincount(assign, minlength=k)
         nc = int(counts.max())
@@ -178,7 +234,8 @@ class Partition:
             members = np.nonzero(assign == c)[0]
             idx[c, : len(members)] = members
             mask[c, : len(members)] = True
-        return Partition(assign=assign, idx=idx, mask=mask, k=k, nc=nc, model=model)
+        return Partition(assign=assign, idx=idx, mask=mask, k=k, nc=nc,
+                         model=model, redirected=redirected)
 
     def gather(self, A: Array) -> Array:
         """Gather per-cluster values: (n, ...) -> (k, nc, ...); pads read row 0."""
@@ -211,7 +268,8 @@ def two_step_kernel_kmeans(
 
     The host part is named by spans ``<span_prefix>/fetch`` (the wait for
     the device and the copy of the distances or the assignment),
-    ``<span_prefix>/balance`` (``balanced_assign``) and
+    ``<span_prefix>/balance`` (``balanced_assign``; identifiers
+    ``redirected`` and ``steps``, the greedy's counters) and
     ``<span_prefix>/partition`` (``Partition.build``); a fit passes
     ``divide/level<l>``."""
     n = X.shape[0]
@@ -229,14 +287,18 @@ def two_step_kernel_kmeans(
     _, W, s = kernel_kmeans(Kmm, k, key_init, iters=iters)
     model = KKMeansModel(Xm=Xm, W=W, s=s)
     assign, D = assign_points(kernel, model, X, use_pallas=use_pallas)
+    redirected = 0
     if balanced:
         capacity = -(-n // k)  # ceil
+        nearest = _nearest_two(D)
         with span(f"{span_prefix}/fetch"):
             D = np.asarray(D)
-        with span(f"{span_prefix}/balance"):
-            assign = balanced_assign(D, capacity)
+        with span(f"{span_prefix}/balance") as tag:
+            assign, redirected, steps = _balance(D, capacity, nearest)
+            tag(redirected=redirected, steps=steps)
     else:
         with span(f"{span_prefix}/fetch"):
             assign = np.asarray(assign)
     with span(f"{span_prefix}/partition"):
-        return Partition.build(np.asarray(assign, np.int32), k, model)
+        return Partition.build(np.asarray(assign, np.int32), k, model,
+                               redirected=redirected)

@@ -6,7 +6,8 @@ the XLA profiler the device timeline carries the identical labels as our
 host-side tree — that naming contract is the whole point (DESIGN.md §13).
 ``span("serve/compute", batch=7)`` adds identifiers: they ride on the
 annotation as stats (its event name stays the bare span name) and on the
-tracer's record.
+tracer's record; ``with span(...) as tag: ...; tag(steps=n)`` adds them at
+the end of the phase.
 
 Host-side recording only happens while a ``SpanTracer`` is activated
 (``with tracer.activate(): fit(...)``); otherwise ``span`` costs one
@@ -36,7 +37,7 @@ import time
 from contextlib import contextmanager
 from contextvars import ContextVar
 from dataclasses import dataclass, field
-from typing import Any, Dict, Iterator, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
 import jax
 
@@ -158,17 +159,22 @@ class SpanTracer:
 
 
 @contextmanager
-def span(name: str, **ids: Any) -> Iterator[None]:
+def span(name: str, **ids: Any) -> Iterator[Callable[..., None]]:
     """Name a program phase: host span tree (when a tracer is active) +
     profiler annotation (always — free unless a profiler session runs).
-    ``ids`` (e.g. ``batch=7``) go to both as identifiers."""
+    ``ids`` (e.g. ``batch=7``) go to both as identifiers; so do those given
+    to the yielded ``tag(**ids)``, for counts known only at the end of the
+    phase."""
     tracer = _ACTIVE
-    with jax.profiler.TraceAnnotation(name, **ids):
+    with jax.profiler.TraceAnnotation(name, **ids) as ann:
         if tracer is None:
-            yield
+            yield ann.set_metadata
         else:
-            with tracer.span(name, **ids):
-                yield
+            with tracer.span(name, **ids) as s:
+                def tag(**more: Any) -> None:
+                    ann.set_metadata(**more)
+                    s.ids.update(more)
+                yield tag
 
 
 # The collection in progress: (annotation, start_ns or None).  Collections

@@ -250,3 +250,36 @@ def test_fit_spans_name_the_host_work(early):
                 f"{level}/fetch", f"{level}/balance", f"{level}/partition"]
     own = root.duration - sum(c.duration for c in root.children)
     assert 0 <= own < 0.05 * root.duration, (own, root.duration)
+
+
+def test_balance_counters_reach_level_stats_and_spans():
+    """Each level's stats carry ``balance_redirected``, the points the
+    balanced assignment moved off their nearest centre, and the level's
+    ``divide/level<l>/balance`` span carries it as ``redirected`` with the
+    greedy's block ``steps``."""
+    from repro.core import assign_points
+    from repro.obs.spans import SpanTracer
+
+    Xtr, ytr, _, _ = _dataset(1200, key=47)
+    n = Xtr.shape[0]
+    cfg = DCSVMConfig(kernel=KERN, C=4.0, k=4, levels=2, m=200, tol=1e-3,
+                      early_stop_level=1)
+    tracer = SpanTracer()
+    with tracer.activate():
+        model = fit(cfg, Xtr, ytr)
+    spans, stack = {}, list(tracer.roots)
+    while stack:
+        s = stack.pop()
+        spans[s.name] = s
+        stack.extend(s.children)
+    assert [st["level"] for st in model.level_stats] == [2, 1]
+    for st in model.level_stats:
+        ids = spans[f"divide/level{st['level']}/balance"].ids
+        assert set(ids) == {"redirected", "steps"}
+        assert st["balance_redirected"] == ids["redirected"]
+        assert 0 <= ids["redirected"] < n
+        assert 1 <= ids["steps"] <= 2 * cfg.k ** st["level"] + 1
+    part = model.partition
+    nearest = np.asarray(assign_points(KERN, part.model, Xtr)[0])
+    assert model.level_stats[-1]["balance_redirected"] == int(
+        np.count_nonzero(part.assign != nearest))

@@ -1,4 +1,6 @@
 """Tests for two-step kernel kmeans + balanced partitioning."""
+import zlib
+
 import numpy as np
 import pytest
 import jax
@@ -57,6 +59,125 @@ def test_balanced_assign_prefers_near_centers():
     D = np.array([[0.1, 5.0]] * 8 + [[5.0, 0.1]] * 8)
     out = balanced_assign(D, capacity=8)
     assert (out[:8] == 0).all() and (out[8:] == 1).all()
+
+
+def _one_at_a_time(D, capacity):
+    """The balance rule one point at a time, with stable sorts: points by
+    confidence (largest first, ties by index), each to its nearest centre
+    with room (ties by index)."""
+    D = np.asarray(D, dtype=np.float64)
+    n, k = D.shape
+    pref = np.argsort(D, axis=1, kind="stable")
+    if k > 1:
+        part = np.partition(D, 1, axis=1)
+        confidence = part[:, 1] - part[:, 0]
+    else:
+        confidence = np.zeros(n)
+    remaining = np.full(k, capacity, dtype=np.int64)
+    out = np.full(n, -1, dtype=np.int32)
+    for i in np.argsort(-confidence, kind="stable"):
+        for c in pref[i]:
+            if remaining[c] > 0:
+                out[i] = c
+                remaining[c] -= 1
+                break
+    return out
+
+
+def _kmeans_distances(k, n=600, m=120, seed=0):
+    """(n, k) float32 distances of a small two-step kernel k-means model;
+    a centre no sampled point reached has a +inf column."""
+    X, _ = gaussian_mixture(jax.random.PRNGKey(seed), n, d=6, modes_per_class=3)
+    kern = Kernel("rbf", gamma=4.0)
+    part = two_step_kernel_kmeans(kern, X, k=k, key=jax.random.PRNGKey(seed + 1),
+                                  m=m, balanced=False,
+                                  span_prefix="divide/level1")
+    return np.asarray(assign_points(kern, part.model, X)[1])
+
+
+def _balance_case(name):
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+    rand = lambda n, k: rng.random((n, k), dtype=np.float32)
+    if name == "k1":
+        return rand(37, 1), 37
+    if name == "k2_ragged":
+        return rand(101, 2), 51
+    if name == "k4_full":
+        return rand(128, 4), 32
+    if name == "k64_ragged":
+        return rand(1000, 64), 16
+    if name == "k64_full":
+        return rand(64 * 12, 64), 12
+    if name == "k64_column_major":
+        # the layout a TPU hands narrow (n, k) arrays over in
+        return np.asfortranarray(rand(1000, 64)), 16
+    if name == "k256_ragged":
+        return rand(3001, 256), 12
+    if name == "k256_full":
+        return rand(256 * 8, 256), 8
+    if name == "ties":
+        # few distinct values: tied confidences and tied row minima
+        return (rng.integers(0, 4, (500, 16)) / 4).astype(np.float32), 32
+    if name == "duplicate_minima":
+        D = rand(400, 8)
+        D[::3, 5] = D[::3].min(axis=1)      # a second centre at the minimum
+        D[::7, 0] = D[::7].min(axis=1)
+        return D, 50
+    if name == "inf_columns":
+        # empty centres: every row +inf there; the capacity is tight, so
+        # they must still be filled, in index order, after the finite ones
+        D = rand(64 * 10, 64)
+        D[:, [3, 17, 40, 63]] = np.inf
+        return D, 10
+    if name == "kmeans_k16":
+        D = _kmeans_distances(16)
+        return D, -(-D.shape[0] // 16)
+    if name == "kmeans_k64":
+        D = _kmeans_distances(64)
+        return D, -(-D.shape[0] // 64)
+    if name == "kmeans_k64_empty":
+        # more centres than sampled points: 24 empty centres, +inf columns
+        D = _kmeans_distances(64, m=40)
+        return D, -(-D.shape[0] // 64)
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("name", [
+    "k1", "k2_ragged", "k4_full", "k64_ragged", "k64_full",
+    "k64_column_major", "k256_ragged",
+    "k256_full", "ties", "duplicate_minima", "inf_columns", "kmeans_k16",
+    "kmeans_k64", "kmeans_k64_empty"])
+def test_balanced_assign_matches_one_at_a_time_rule(name):
+    """The blocked greedy gives exactly the one-point-at-a-time rule with
+    stable tie-breaking, and counts the points it moved off their nearest
+    centre and its block steps: a full block of B = ceil(n / k) points, the
+    last partial one, or one that stops early and shuts a centre."""
+    from repro.core.kkmeans import _balance, _nearest_two
+
+    D, cap = _balance_case(name)
+    n, k = D.shape
+    want = _one_at_a_time(D, cap)
+    out, redirected, steps = _balance(D, cap, _nearest_two(D))
+    np.testing.assert_array_equal(balanced_assign(D, cap), want)
+    np.testing.assert_array_equal(out, want)
+    assert out.dtype == np.int32
+    assert np.bincount(out, minlength=k).max() <= cap
+    assert redirected == int(np.count_nonzero(
+        want != np.argsort(D, axis=1, kind="stable")[:, 0]))
+    assert 1 <= steps <= n // -(-n // k) + k + 1
+    if name in ("inf_columns", "kmeans_k64_empty"):
+        # the finite centres fill first, then the empty ones in index order
+        counts = np.bincount(out, minlength=k)
+        empty = np.isinf(D).all(axis=0)
+        assert empty.sum() >= 4 and (counts[~empty] == cap).all()
+        assert counts[empty][0] > 0 and (np.diff(counts[empty]) <= 0).all()
+    if name.startswith("kmeans"):
+        assert redirected > 0
+
+
+def test_balanced_assign_rejects_too_little_capacity():
+    with pytest.raises(ValueError, match="capacity"):
+        balanced_assign(np.zeros((33, 4), np.float32), 8)
 
 
 def test_partition_gather_scatter_roundtrip():

@@ -325,6 +325,38 @@ def test_span_ids_kept():
     assert ev["serve/batch"]["tid"] == threading.get_native_id()
 
 
+def test_span_tag_adds_ids_at_the_end(tmp_path):
+    """``tag(**ids)`` from ``with span(...) as tag`` adds identifiers known
+    only at the end of the phase, to the tracer's record and to the
+    profiler annotation, and costs nothing without a tracer."""
+    from jax.profiler import ProfileData
+
+    tracer = SpanTracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracer.activate():
+            with span("tag/late", batch=2) as tag:
+                tag(redirected=5, steps=3)
+        with span("tag/untraced") as tag:
+            tag(steps=4)
+    finally:
+        jax.profiler.stop_trace()
+    (s,) = tracer.roots
+    assert s.ids == {"batch": 2, "redirected": 5, "steps": 3}
+    ev = {e["name"]: e for e in tracer.chrome_trace()["traceEvents"]}
+    assert ev["tag/late"]["args"] == {"batch": 2, "redirected": 5, "steps": 3}
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    stats = {}
+    for plane in ProfileData.from_file(path[0]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("tag/"):
+                        stats[e.name] = dict(e.stats)
+    assert stats == {"tag/late": {"batch": 2, "redirected": 5, "steps": 3},
+                     "tag/untraced": {"steps": 4}}
+
+
 def test_span_clock_agrees_with_the_profiler(tmp_path):
     """The tracer and the profiler's host tracer stamp the same spans: the
     distance between two span starts agrees within 0.2 ms, and an
