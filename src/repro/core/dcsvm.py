@@ -340,6 +340,24 @@ def _stack_results(results: List[S.SolveResult]) -> S.SolveResult:
     return S.SolveResult(*(stack_field(f) for f in S.SolveResult._fields))
 
 
+def _level0_dense(cfg: DCSVMConfig, td: TaskDual) -> bool:
+    """Whether level 0 solves against a dense Gram; otherwise it runs the
+    operator engines (matvec or, for the box family, host spill).
+    ``host_spill`` routes the box family out-of-core even under the dense
+    threshold (the flag's meaning is "never materialize the level-0 Gram");
+    equality tasks stay on their dense/matvec engines."""
+    spill = cfg.host_spill and not td.has_equality
+    return td.n_dual <= cfg.full_gram_threshold and not spill
+
+
+def _level0_subqp(cfg: DCSVMConfig, td: TaskDual, use_pallas: bool) -> str:
+    """The B×B sub-QP sweep the level-0 solve runs: ``"pallas"`` where the
+    box family's operator engines run on the Pallas backend (the
+    ``small_qp_sweep`` kernel), ``"xla"`` elsewhere."""
+    operator = not _level0_dense(cfg, td) and not td.has_equality
+    return "pallas" if operator and use_pallas else "xla"
+
+
 def _solve_full(cfg: DCSVMConfig, td: TaskDual, alpha: Array,
                 use_pallas: bool = False):
     """Top-level (level 0) solve on the whole generalized dual, warm-started.
@@ -359,11 +377,8 @@ def _solve_full(cfg: DCSVMConfig, td: TaskDual, alpha: Array,
         return trace_init(cfg.trace) if cfg.trace else None
 
     dedup = cfg.gram_dedup and td.n_base != n and not td.has_equality
-    # host_spill routes the box family out-of-core even under the dense
-    # threshold (the flag's meaning is "never materialize the level-0 Gram");
-    # equality tasks stay on their dense/matvec engines
     spill = cfg.host_spill and not td.has_equality
-    if n <= cfg.full_gram_threshold and not spill:
+    if _level0_dense(cfg, td):
         if dedup:
             # base-indexed dense Gram: n_base^2 kernel evals instead of
             # n_dual^2, gathered to dual coordinates (bit-identical values)
@@ -575,7 +590,8 @@ def _fit_algorithm1(
         with span("conquer/refine"):
             alpha = _solve_subset(cfg, td, alpha, jnp.asarray(sv_idx),
                                   use_pallas=use_pallas)
-    with span("conquer/solve"):
+    subqp = _level0_subqp(cfg, td, use_pallas)
+    with span("conquer/solve", subqp=subqp):
         res = _solve_full(cfg, td, alpha, use_pallas=use_pallas)
         alpha = res.alpha
         alpha.block_until_ready()
@@ -586,7 +602,7 @@ def _fit_algorithm1(
                   train_time=time.perf_counter() - t0,
                   n_sv=int(len(sv_base0)),
                   iters=int(np.sum(np.asarray(res.iters))),
-                  pg_max=float(np.max(np.asarray(res.pg_max))))
+                  pg_max=float(np.max(np.asarray(res.pg_max))), subqp=subqp)
         if res.cache_hits is not None:
             hits = int(np.sum(np.asarray(res.cache_hits)))
             misses = int(np.sum(np.asarray(res.cache_misses)))

@@ -265,7 +265,8 @@ def _panel_block_cd(op: GramOperator, tile: Array, pstart, alpha: Array,
         Qrows = op.expand_rows(kr, sel)                     # (B, n) signed
         ab = jnp.where(valid, alpha[sel], 0.0).astype(acc)
         cb = jnp.where(valid, cvec[sel], 0.0)
-        new_ab = _solve_small_qp(Qrows[:, sel], g[sel], ab, cb, sweeps)
+        new_ab = _solve_small_qp(Qrows[:, sel], g[sel], ab, cb, sweeps,
+                                 use_pallas=op.use_pallas)
         delta = jnp.where(valid, new_ab - ab, 0.0)
         alpha = alpha.at[sel].add(delta.astype(alpha.dtype))
         g = g + f32_matmul(delta, Qrows)

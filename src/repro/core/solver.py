@@ -240,13 +240,23 @@ def solve_box_qp(
 # Block greedy CD (beyond-paper batched variant)
 # ---------------------------------------------------------------------------
 
-def _solve_small_qp(Qbb: Array, gb: Array, ab: Array, cb, sweeps: int) -> Array:
+def _solve_small_qp(Qbb: Array, gb: Array, ab: Array, cb, sweeps: int,
+                    use_pallas: bool = False) -> Array:
     """Cyclic CD on the BxB subproblem. g_b is the gradient at entry; we
     maintain it locally.  ``cb`` is the upper bound, scalar or the (B,)
-    slice of the per-coordinate box.  Returns the new a_b."""
+    slice of the per-coordinate box.  Returns the new a_b.
+
+    ``use_pallas`` runs the whole sweep as one Pallas kernel
+    (``kernels.small_qp``) with the same steps in the same order; otherwise
+    each step is a trip of the ``fori_loop`` below."""
     B = Qbb.shape[0]
     cb = _broadcast(cb, B, Qbb.dtype)
     diag = jnp.maximum(jnp.diagonal(Qbb), 1e-12)
+    if use_pallas:
+        from repro.kernels import ops as kops
+        # the kernel reads Qbb's column j as row j of Qbb.T (Qbb, a matmul's
+        # output, need not be bitwise symmetric)
+        return kops.small_qp_sweep(Qbb.T, gb, ab, cb, diag, sweeps=sweeps)
 
     def body(t, carry):
         a, g = carry
@@ -438,7 +448,8 @@ def solve_box_qp_op(
 
     def solve_block(Qbb, alpha, g, idx):
         ab, gb = alpha[idx], g[idx]
-        new_ab = _solve_small_qp(Qbb, gb, ab, cvec[idx], sweeps)
+        new_ab = _solve_small_qp(Qbb, gb, ab, cvec[idx], sweeps,
+                                 use_pallas=op.use_pallas)
         return new_ab, new_ab - ab
 
     def record(tr, alpha, g, pg_max, cache_hits=None):

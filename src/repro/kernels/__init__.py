@@ -7,6 +7,8 @@
 #   cd_update.py     fused on-the-fly kernel-column block gradient update for
 #                    the conquer-step block CD (recompute-in-VMEM; the optional
 #                    device-resident column cache lives in core.colcache)
+#   small_qp.py      the conquer step's B×B sub-QP coordinate-descent sweep,
+#                    all sweeps * B steps in one kernel with Qbb in VMEM
 # ops.py exposes jit'd wrappers (interpret mode on CPU, compiled on TPU);
 # ref.py holds the pure-jnp oracles the tests compare against.
 from repro.kernels import ops, ref

@@ -16,6 +16,7 @@ from repro.kernels import kermat as _kermat
 from repro.kernels import kermatvec as _kermatvec
 from repro.kernels import kmeans_assign as _assign
 from repro.kernels import cd_update as _cd
+from repro.kernels import small_qp as _small_qp
 
 
 def _interpret() -> bool:
@@ -128,3 +129,12 @@ def cd_column_update(X: jax.Array, y: jax.Array, Xb: jax.Array, w: jax.Array,
         compute_dtype=_cd_static(compute_dtype, X.dtype),
     )
     return out[:n]
+
+
+def small_qp_sweep(QbbT: jax.Array, gb: jax.Array, ab: jax.Array,
+                   cb: jax.Array, diag: jax.Array, *, sweeps: int) -> jax.Array:
+    """New a_b after ``sweeps`` cyclic CD passes on the B×B box sub-QP, the
+    whole sweep in one Pallas kernel.  ``QbbT`` is ``Qbb.T``; ``cb`` and
+    ``diag`` (the guarded diagonal of ``Qbb``) are (B,) vectors."""
+    return _small_qp.small_qp_sweep(QbbT, gb, ab, cb, diag, sweeps=sweeps,
+                                    interpret=_interpret())

@@ -486,3 +486,101 @@ def test_shrinking_iters_accumulate_on_device():
     assert isinstance(res.iters, jax.Array)
     assert res.iters.dtype == jnp.int32
     assert int(res.iters) > 0
+
+
+# ---------------------------------------------------------------------------
+# The B x B sub-QP sweep as one Pallas kernel (kernels/small_qp.py)
+# ---------------------------------------------------------------------------
+
+def _subqp(B, seed=0, cb="vec", start="inside", zero_diag=False):
+    """An RBF-shaped signed (B, B) block, perturbed so that it is not bitwise
+    symmetric (the kernel must read Qbb's columns, not its rows), with its
+    gradient, start and box."""
+    r = np.random.default_rng(seed)
+    Z = r.uniform(size=(B, 5))
+    s = np.sign(r.normal(size=B))
+    Q = s[:, None] * s[None, :] * np.exp(
+        -2.0 * ((Z[:, None] - Z[None]) ** 2).sum(-1))
+    Q = Q + 1e-4 * r.normal(size=(B, B))
+    if zero_diag:
+        Q[B // 2, B // 2] = 0.0          # the kernel meets the 1e-12 guard
+    c = r.uniform(0.5, 4.0, size=B) if cb == "vec" else np.full(B, 2.0)
+    a = {"zero": np.zeros(B), "box": c,
+         "inside": r.uniform(size=B) * c * (r.uniform(size=B) < 0.6)}[start]
+    g = r.normal(size=B)
+    f32 = lambda v: jnp.asarray(v, jnp.float32)
+    return f32(Q), f32(g), f32(a), (f32(c) if cb == "vec" else 2.0)
+
+
+SUBQP_CASES = (
+    [dict(B=B, sweeps=w) for B in (1, 7, 64, 100, 256) for w in (1, 4)]
+    + [dict(B=64, sweeps=4, cb="scalar"),
+       dict(B=64, sweeps=4, start="zero"),
+       dict(B=64, sweeps=4, start="box"),
+       dict(B=7, sweeps=4, start="box", cb="scalar"),
+       dict(B=64, sweeps=4, zero_diag=True),
+       dict(B=100, sweeps=4, vmap=3)])
+
+
+@pytest.mark.parametrize(
+    "case", SUBQP_CASES,
+    ids=["-".join(f"{k}{v}" for k, v in c.items()) for c in SUBQP_CASES])
+def test_small_qp_sweep_bitwise_matches_xla_loop(case):
+    """``ops.small_qp_sweep`` (interpret mode here) takes the XLA loop's
+    steps in the XLA loop's order with its arithmetic: bitwise equal."""
+    from repro.core.solver import _broadcast, _solve_small_qp
+    from repro.kernels import ops
+
+    case = dict(case)
+    B, sweeps, nv = case.pop("B"), case.pop("sweeps"), case.pop("vmap", 0)
+
+    def both(Q, g, a, c):
+        want = _solve_small_qp(Q, g, a, c, sweeps)
+        got = ops.small_qp_sweep(Q.T, g, a, _broadcast(c, B, Q.dtype),
+                                 jnp.maximum(jnp.diagonal(Q), 1e-12),
+                                 sweeps=sweeps)
+        return want, got
+
+    if nv:
+        probs = [_subqp(B, seed=s, **case) for s in range(nv)]
+        args = [jnp.stack(x) for x in zip(*probs)]
+        want, got = jax.vmap(both)(*args)
+    else:
+        Q, g, a, c = _subqp(B, **case)
+        assert B == 1 or not bool(jnp.all(Q == Q.T))
+        want, got = both(Q, g, a, c)
+    assert got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_matvec_solver_small_qp_kernel_parity():
+    """The operator solver with the Pallas backend (fused column update and
+    the ``small_qp_sweep`` kernel) against the XLA backend on a small RBF
+    problem: the same iterations, alpha within 1e-6."""
+    X, y = _problem(n=160, d=7, key=5)
+    kern = Kernel("rbf", gamma=4.0)
+    r_x = solve_box_qp_matvec(X, y, kern, 2.0, tol=1e-3, max_iters=500,
+                              block=16)
+    r_p = solve_box_qp_matvec(X, y, kern, 2.0, tol=1e-3, max_iters=500,
+                              block=16, use_pallas=True)
+    assert int(r_p.iters) == int(r_x.iters)
+    np.testing.assert_allclose(np.asarray(r_p.alpha), np.asarray(r_x.alpha),
+                               atol=1e-6)
+
+
+def test_fit_level0_stats_name_the_subqp_path():
+    """A fit's level-0 stats say which sweep ran: the kernel on the Pallas
+    operator path, the XLA loop on the XLA backend and on the dense path."""
+    X, y = gaussian_mixture(jax.random.PRNGKey(4), 300, d=6,
+                            modes_per_class=3, spread=0.2)
+    cfg = DCSVMConfig(kernel=Kernel("rbf", gamma=4.0), C=2.0, k=2, levels=1,
+                      m=100, tol=1e-3, full_gram_threshold=64,
+                      use_pallas=True)
+    seen = {}
+    for name, c in {"pallas": cfg,
+                    "xla": dataclasses.replace(cfg, use_pallas=False),
+                    "dense": dataclasses.replace(cfg,
+                                                 full_gram_threshold=10**6)
+                    }.items():
+        seen[name] = fit(c, X, y).level_stats[-1]["subqp"]
+    assert seen == {"pallas": "pallas", "xla": "xla", "dense": "xla"}

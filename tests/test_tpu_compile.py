@@ -2,8 +2,9 @@
 
 The TPU compiler is installed even where no chip is attached; these tests
 lower the main path's Pallas kernels at the covtype (d=54) and webspam
-(d=254) widths, plus one level-1 cluster solve at the paper deployment's
-cluster size, on one chip and sharded over the four of a ``v5e:2x2``.
+(d=254) widths and the conquer's sub-QP sweep kernel, plus one level-1
+cluster solve at the paper deployment's cluster size, on one chip and
+sharded over the four of a ``v5e:2x2``.
 They catch what interpret mode cannot: a kernel Mosaic refuses (tiling,
 VMEM, an unsupported precision), a kernel inside ``shard_map`` without
 varying-axes types, an XLA replacement of a kernel, or a program that
@@ -80,6 +81,20 @@ def test_kernel_compiles_for_v5e(one_chip, compiled_kernels, name, d):
     args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
             for s in shapes(d)]
     hlo, total = _compile(fn, *args)
+    assert "tpu_custom_call" in hlo
+    assert total < HBM_BYTES
+
+
+@pytest.mark.parametrize("B", [64, 256])
+def test_small_qp_sweep_compiles_for_v5e(one_chip, compiled_kernels, B):
+    """The conquer's B×B sub-QP sweep kernel at the solver's block (64) and
+    at two lane tiles: its dynamic row reads and lane reductions must pass
+    Mosaic, which interpret mode does not check."""
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in [(B, B), (B,), (B,), (B,), (B,)]]
+    hlo, total = _compile(
+        lambda QT, g, a, c, d: ops.small_qp_sweep(QT, g, a, c, d, sweeps=4),
+        *args)
     assert "tpu_custom_call" in hlo
     assert total < HBM_BYTES
 
