@@ -189,9 +189,9 @@ class ConquerConfig:
                              # Cached Q-row slices store in this dtype too,
                              # doubling the rows a byte budget holds
     trace_cap: int = 0       # convergence-trace ring capacity (obs.trace);
-                             # 0 = off (jaxpr identical to the pre-trace
-                             # program); > 0 records one sample per round
-                             # and conquer_step returns a 4th ConvTrace
+                             # 0 = off (no ring in the program); > 0
+                             # records one sample per round and
+                             # conquer_step returns a 4th ConvTrace
                              # element
 
 
@@ -223,8 +223,9 @@ def conquer_step(
     so the ring is replicated across devices) plus the CE-PBM combination
     step γ* — is recorded on device and a 4th ``ConvTrace`` element is
     returned; fetch it with ``obs.trace.trace_fetch`` AFTER the loop.  The
-    trace adds two scalar psums per round and nothing else; ``trace_cap=0``
-    (the default) builds the identical pre-trace program.
+    trace adds two scalar psums per round and nothing else; with
+    ``trace_cap=0`` (the default) the ring is an empty carry and nothing of
+    it enters the program.
     """
     if cfg.mode not in ("parallel", "replicated"):
         raise ValueError(f"unknown conquer mode {cfg.mode!r} "
@@ -361,16 +362,6 @@ def conquer_step(
             return ((ssel[:, None] * sl[None, :])
                     * pairwise(Xsel, Xl)).astype(acc)
 
-        tcap = cfg.trace_cap
-
-        def cond(state):
-            it, pg = state[-2], state[-1]
-            return (pg > cfg.tol) & (it < cfg.max_iters)
-
-        def cond_t(state):
-            it, pg = state[-3], state[-2]
-            return (pg > cfg.tol) & (it < cfg.max_iters)
-
         def record_round(tr, al, g_l, pg, gamma=None, cache_hits=None):
             """One post-update sample per round; the psum-reduced columns
             make every device's ring identical, so the caller reads shard 0."""
@@ -385,7 +376,7 @@ def conquer_step(
                                          cache_hits=cache_hits), axis)
 
         pg0 = lax.pmax(jnp.max(scores_of(al, g_l)), axis)
-        tr = None
+        x = (al, g_l)
 
         if cfg.mode == "parallel" and cache_cap == 0:
             def step(al, g_l):
@@ -393,25 +384,6 @@ def conquer_step(
                 g_l = g_l + qdelta(Xsel, ssel, ssel * asel)
                 al = al.at[ib].set(a_new)
                 return al, g_l, pg, gamma
-
-            if tcap == 0:
-                def body(state):
-                    al, g_l, it, _ = state
-                    al, g_l, pg, _ = step(al, g_l)
-                    return al, g_l, it + 1, pg
-
-                state0 = (al, g_l, jnp.zeros((), jnp.int32), pg0)
-                al, g_l, rounds, _ = lax.while_loop(cond, body, state0)
-            else:
-                def body(state):
-                    al, g_l, it, _, tr = state
-                    al, g_l, pg, gamma = step(al, g_l)
-                    tr = record_round(tr, al, g_l, pg, gamma)
-                    return al, g_l, it + 1, pg, tr
-
-                state0 = (al, g_l, jnp.zeros((), jnp.int32), pg0,
-                          _varying(trace_init(tcap), axis))
-                al, g_l, rounds, _, tr = lax.while_loop(cond_t, body, state0)
 
         elif cfg.mode == "parallel":
             def step(al, g_l, cache):
@@ -434,35 +406,11 @@ def conquer_step(
             # fits twice the rows of f32 under the same byte budget
             store = (jnp.dtype(compute_dtype) if compute_dtype is not None
                      else acc)
-            cache0 = _varying(colcache.init(cache_cap, n, dtype=store,
-                                            width=n_l), axis)
-
-            if tcap == 0:
-                def body(state):
-                    al, g_l, cache, it, _ = state
-                    al, g_l, cache, pg, _ = step(al, g_l, cache)
-                    return al, g_l, cache, it + 1, pg
-
-                state0 = (al, g_l, cache0, jnp.zeros((), jnp.int32), pg0)
-                al, g_l, _, rounds, _ = lax.while_loop(cond, body, state0)
-            else:
-                def body(state):
-                    al, g_l, cache, it, _, tr = state
-                    hits0 = cache.hits
-                    al, g_l, cache, pg, gamma = step(al, g_l, cache)
-                    # per-round local cache-hit delta (identical across
-                    # devices — lookups key on the replicated gidx)
-                    tr = record_round(tr, al, g_l, pg, gamma,
-                                      cache_hits=cache.hits - hits0)
-                    return al, g_l, cache, it + 1, pg, tr
-
-                state0 = (al, g_l, cache0, jnp.zeros((), jnp.int32), pg0,
-                          _varying(trace_init(tcap), axis))
-                al, g_l, _, rounds, _, tr = lax.while_loop(cond_t, body,
-                                                           state0)
+            x += (_varying(colcache.init(cache_cap, n, dtype=store,
+                                         width=n_l), axis),)
 
         else:   # replicated: legacy exact global GS-B baseline
-            def rep_step(al, g_l):
+            def step(al, g_l):
                 sc_ = scores_of(al, g_l)
                 sb, ib = lax.top_k(sc_, B)              # local candidates
                 cand = dict(sc=sb, x=Xl[ib], g=g_l[ib], a=al[ib], y=sl[ib],
@@ -489,32 +437,32 @@ def conquer_step(
                 al = al.at[safe_idx].add(
                     jnp.where(own, delta, 0.0).astype(dtype))
                 pg = lax.pmax(jnp.max(sc_), axis)
-                return al, g_l, pg
+                # no combination step: the trace's gamma column stays NaN
+                return al, g_l, pg, None
 
-            if tcap == 0:
-                def body(state):
-                    al, g_l, it, _ = state
-                    al, g_l, pg = rep_step(al, g_l)
-                    return al, g_l, it + 1, pg
+        def cond(state):
+            _, it, pg, _ = state
+            return (pg > cfg.tol) & (it < cfg.max_iters)
 
-                state0 = (al, g_l, jnp.zeros((), jnp.int32), pg0)
-                al, g_l, rounds, _ = lax.while_loop(cond, body, state0)
-            else:
-                def body(state):
-                    al, g_l, it, _, tr = state
-                    al, g_l, pg = rep_step(al, g_l)
-                    # no combination step in the replicated baseline:
-                    # the gamma column stays NaN
-                    tr = record_round(tr, al, g_l, pg)
-                    return al, g_l, it + 1, pg, tr
+        def body(state):
+            x, it, _, tr = state
+            *x_new, pg, gamma = step(*x)
+            x_new = tuple(x_new)
+            if tr is not None:
+                # the cache-hit delta is identical across devices: lookups
+                # key on the replicated gidx
+                hits = x_new[2].hits - x[2].hits if cache_cap > 0 else None
+                tr = record_round(tr, *x_new[:2], pg, gamma, cache_hits=hits)
+            return x_new, it + 1, pg, tr
 
-                state0 = (al, g_l, jnp.zeros((), jnp.int32), pg0,
-                          _varying(trace_init(tcap), axis))
-                al, g_l, rounds, _, tr = lax.while_loop(cond_t, body, state0)
+        tr0 = (_varying(trace_init(cfg.trace_cap), axis)
+               if cfg.trace_cap > 0 else None)
+        (al, g_l, *_), rounds, _, tr = lax.while_loop(
+            cond, body, (x, jnp.zeros((), jnp.int32), pg0, tr0))
 
         # residual at the RETURNED alpha, not the pre-update stopping value
         pg_exit = lax.pmax(jnp.max(scores_of(al, g_l)), axis)
-        if tcap == 0:
+        if tr is None:
             return al, rounds[None], pg_exit[None]
         # the ring is replicated (psum/pmax-reduced columns): ship every
         # device's copy out and let the caller read shard 0

@@ -160,6 +160,48 @@ def combination_step_size(gTd: Array, dQd: Array) -> Array:
 
 
 # ---------------------------------------------------------------------------
+# The box family's loop: one skeleton drives every box CD engine
+# ---------------------------------------------------------------------------
+
+def _cd_loop(step, record, x, pg0, tol, max_iters, trace):
+    """Run ``step`` until the stopping value is at most ``tol`` or
+    ``max_iters`` iterations have run.
+
+    ``x`` is the engine's carry, ``(alpha, g)`` plus the column cache on the
+    cached matvec path; ``step(*x)`` returns the new carry followed by the
+    stopping value of the iterate it improved.  ``trace`` is an optional
+    carry: ``None`` has no leaves, so with tracing off the loop state
+    flattens to the leaves of a loop without it and nothing of
+    ``record(tr, x, x_new, pg_max)`` enters the program.  Returns
+    ``(x, iters, pg_max, trace)``.
+    """
+    def cond(state):
+        _, it, pg_max, _ = state
+        return (pg_max > tol) & (it < max_iters)
+
+    def body(state):
+        x, it, _, tr = state
+        *x_new, pg_max = step(*x)
+        x_new = tuple(x_new)
+        tr = tr if tr is None else record(tr, x, x_new, pg_max)
+        return x_new, it + 1, pg_max, tr
+
+    return lax.while_loop(cond, body, (x, 0, pg0, trace))
+
+
+def _masked_record(mask, cvec, pvec):
+    """Recorder of the dense box engines: (pg_max, objective, n_free) of
+    the pre-update iterate, over the active coordinates."""
+    def record(tr, x, _x_new, _pg_max):
+        alpha, g = x
+        return trace_record(tr, pg_max=jnp.max(jnp.abs(jnp.where(
+                                mask, proj_grad(alpha, g, cvec), 0.0))),
+                            objective=objective(alpha, g, pvec),
+                            n_free=_n_free(alpha, cvec, mask))
+    return record
+
+
+# ---------------------------------------------------------------------------
 # Greedy single-coordinate CD (paper-faithful conquer/sub-solver)
 # ---------------------------------------------------------------------------
 
@@ -182,7 +224,7 @@ def solve_box_qp(
     are never selected (their pg is treated as 0 for selection AND stopping,
     matching LIBSVM's shrunk working set).
 
-    ``trace`` (static gate, ``None`` = identical pre-trace jaxpr) records one
+    ``trace`` (static gate, ``None`` records nothing) records one
     (pg_max, objective, n_free) sample per iteration into the ring buffer,
     evaluated at the pre-update iterate like the stopping value.
     """
@@ -204,35 +246,9 @@ def solve_box_qp(
 
     # one priming evaluation so the loop can exit immediately at the optimum
     pg0 = jnp.max(jnp.abs(jnp.where(mask, proj_grad(alpha, g, cvec), 0.0)))
-
-    if trace is None:
-        def cond(state):
-            _, _, it, pg_max = state
-            return (pg_max > tol) & (it < max_iters)
-
-        def body(state):
-            alpha, g, it, _ = state
-            alpha, g, pg_max = step(alpha, g)
-            return alpha, g, it + 1, pg_max
-
-        alpha, g, iters, pg_max = lax.while_loop(cond, body, (alpha, g, 0, pg0))
-        return SolveResult(alpha, g, iters, pg_max)
-
-    def cond_t(state):
-        _, _, it, pg_max, _ = state
-        return (pg_max > tol) & (it < max_iters)
-
-    def body_t(state):
-        alpha, g, it, _, tr = state
-        tr = trace_record(tr, pg_max=jnp.max(jnp.abs(jnp.where(
-                              mask, proj_grad(alpha, g, cvec), 0.0))),
-                          objective=objective(alpha, g, pvec),
-                          n_free=_n_free(alpha, cvec, mask))
-        alpha, g, pg_max = step(alpha, g)
-        return alpha, g, it + 1, pg_max, tr
-
-    alpha, g, iters, pg_max, tr = lax.while_loop(
-        cond_t, body_t, (alpha, g, 0, pg0, trace))
+    (alpha, g), iters, pg_max, tr = _cd_loop(
+        step, _masked_record(mask, cvec, pvec), (alpha, g), pg0, tol,
+        max_iters, trace)
     return SolveResult(alpha, g, iters, pg_max, trace=tr)
 
 
@@ -290,7 +306,7 @@ def solve_box_qp_block(
     update `g += Q[:, idx] @ delta` is a skinny matmul — the MXU-friendly
     reshaping of the paper's one-at-a-time CD.  ``C``/``p`` may be
     per-coordinate vectors (generalized dual).  ``trace`` records one sample
-    per outer (rank-B) iteration; ``None`` keeps the pre-trace jaxpr.
+    per outer (rank-B) iteration, as in ``solve_box_qp``.
     """
     n = Q.shape[0]
     alpha = jnp.zeros(n, Q.dtype) if alpha0 is None else alpha0
@@ -311,35 +327,9 @@ def solve_box_qp_block(
                 jnp.max(scores))
 
     pg0 = jnp.max(jnp.abs(jnp.where(mask, proj_grad(alpha, g, cvec), 0.0)))
-
-    if trace is None:
-        def cond(state):
-            _, _, it, pg_max = state
-            return (pg_max > tol) & (it < max_iters)
-
-        def body(state):
-            alpha, g, it, _ = state
-            alpha, g, pg_max = step(alpha, g)
-            return alpha, g, it + 1, pg_max
-
-        alpha, g, iters, pg_max = lax.while_loop(cond, body, (alpha, g, 0, pg0))
-        return SolveResult(alpha, g, iters, pg_max)
-
-    def cond_t(state):
-        _, _, it, pg_max, _ = state
-        return (pg_max > tol) & (it < max_iters)
-
-    def body_t(state):
-        alpha, g, it, _, tr = state
-        tr = trace_record(tr, pg_max=jnp.max(jnp.abs(jnp.where(
-                              mask, proj_grad(alpha, g, cvec), 0.0))),
-                          objective=objective(alpha, g, pvec),
-                          n_free=_n_free(alpha, cvec, mask))
-        alpha, g, pg_max = step(alpha, g)
-        return alpha, g, it + 1, pg_max, tr
-
-    alpha, g, iters, pg_max, tr = lax.while_loop(
-        cond_t, body_t, (alpha, g, 0, pg0, trace))
+    (alpha, g), iters, pg_max, tr = _cd_loop(
+        step, _masked_record(mask, cvec, pvec), (alpha, g), pg0, tol,
+        max_iters, trace)
     return SolveResult(alpha, g, iters, pg_max, trace=tr)
 
 
@@ -452,17 +442,10 @@ def solve_box_qp_op(
                                  use_pallas=op.use_pallas)
         return new_ab, new_ab - ab
 
-    def record(tr, alpha, g, pg_max, cache_hits=None):
-        # pre-update sample, matching the stopping value's iterate
-        return trace_record(tr, pg_max=pg_max,
-                            objective=objective(alpha, g, pvec),
-                            n_free=_n_free(alpha, cvec),
-                            cache_hits=cache_hits)
-
     if cache_cap > 0:
         cap = max(cache_cap, block)  # must hold at least one full block
 
-        def cache_step(alpha, g, cache):
+        def step(alpha, g, cache):
             idx, pg_max = select(alpha, g)
             keys = op.cache_keys(idx)
             slots, hit = colcache.lookup(cache, keys)
@@ -477,43 +460,7 @@ def solve_box_qp_op(
             new_ab, delta = solve_block(Qrows[:, idx], alpha, g, idx)
             return (alpha.at[idx].set(new_ab), g + f32_matmul(delta, Qrows),
                     cache, pg_max)
-
-        pg0 = jnp.max(jnp.abs(proj_grad(alpha, g, cvec)))
-        cache0 = colcache.init(cap, op.kwidth, dtype=op.storage_dtype(acc),
-                               width=op.kwidth)
-
-        if trace is None:
-            def body(state):
-                alpha, g, cache, it, _ = state
-                alpha, g, cache, pg_max = cache_step(alpha, g, cache)
-                return alpha, g, cache, it + 1, pg_max
-
-            def cond(state):
-                _, _, _, it, pg_max = state
-                return (pg_max > tol) & (it < max_iters)
-
-            alpha, g, cache, iters, pg_max = lax.while_loop(
-                cond, body, (alpha, g, cache0, 0, pg0))
-            return SolveResult(alpha, g, iters, pg_max, cache.hits,
-                               cache.misses, cache_evictions=cache.evictions)
-
-        def body_t(state):
-            alpha, g, cache, it, _, tr = state
-            hits0 = cache.hits
-            alpha2, g2, cache, pg_max = cache_step(alpha, g, cache)
-            tr = record(tr, alpha, g, pg_max, cache_hits=cache.hits - hits0)
-            return alpha2, g2, cache, it + 1, pg_max, tr
-
-        def cond_t(state):
-            _, _, _, it, pg_max, _ = state
-            return (pg_max > tol) & (it < max_iters)
-
-        alpha, g, cache, iters, pg_max, tr = lax.while_loop(
-            cond_t, body_t, (alpha, g, cache0, 0, pg0, trace))
-        return SolveResult(alpha, g, iters, pg_max, cache.hits, cache.misses,
-                           cache_evictions=cache.evictions, trace=tr)
-
-    if op.use_pallas:
+    elif op.use_pallas:
         def step(alpha, g):
             idx, pg_max = select(alpha, g)
             # fused: dg = s * (K(X, Xb) @ (sb * delta)); the (n, B) block
@@ -530,34 +477,27 @@ def solve_box_qp_op(
             new_ab, delta = solve_block(Qbb, alpha, g, idx)
             return alpha.at[idx].set(new_ab), g + f32_matmul(Qb, delta), pg_max
 
+    def record(tr, x, x_new, pg_max):
+        # pre-update sample, matching the stopping value's iterate, with
+        # the step's cache-hit delta when a cache exists
+        alpha, g = x[:2]
+        hits = x_new[2].hits - x[2].hits if cache_cap > 0 else None
+        return trace_record(tr, pg_max=pg_max,
+                            objective=objective(alpha, g, pvec),
+                            n_free=_n_free(alpha, cvec), cache_hits=hits)
+
     pg0 = jnp.max(jnp.abs(proj_grad(alpha, g, cvec)))
-
-    if trace is None:
-        def body(state):
-            alpha, g, it, _ = state
-            alpha, g, pg_max = step(alpha, g)
-            return alpha, g, it + 1, pg_max
-
-        def cond(state):
-            _, _, it, pg_max = state
-            return (pg_max > tol) & (it < max_iters)
-
-        alpha, g, iters, pg_max = lax.while_loop(cond, body, (alpha, g, 0, pg0))
-        return SolveResult(alpha, g, iters, pg_max)
-
-    def body_t(state):
-        alpha, g, it, _, tr = state
-        alpha2, g2, pg_max = step(alpha, g)
-        tr = record(tr, alpha, g, pg_max)
-        return alpha2, g2, it + 1, pg_max, tr
-
-    def cond_t(state):
-        _, _, it, pg_max, _ = state
-        return (pg_max > tol) & (it < max_iters)
-
-    alpha, g, iters, pg_max, tr = lax.while_loop(
-        cond_t, body_t, (alpha, g, 0, pg0, trace))
-    return SolveResult(alpha, g, iters, pg_max, trace=tr)
+    x = (alpha, g)
+    if cache_cap > 0:
+        x += (colcache.init(cap, op.kwidth, dtype=op.storage_dtype(acc),
+                            width=op.kwidth),)
+    (alpha, g, *cache), iters, pg_max, tr = _cd_loop(
+        step, record, x, pg0, tol, max_iters, trace)
+    if not cache:
+        return SolveResult(alpha, g, iters, pg_max, trace=tr)
+    cache, = cache
+    return SolveResult(alpha, g, iters, pg_max, cache.hits, cache.misses,
+                       cache_evictions=cache.evictions, trace=tr)
 
 
 # ---------------------------------------------------------------------------
@@ -872,33 +812,75 @@ def _restore_equality_grouped(alpha, grad, Q_col, cvec, avec, dvec, gid,
     return alpha, grad
 
 
+def _refresh_loop(step, violation, full_grad, alpha, cvec, mask, pvec, tol,
+                  max_iters, refresh_every, trace):
+    """The equality engines' loop: an outer loop of refresh blocks, each an
+    inner loop of up to ``refresh_every`` ``step``s on the maintained
+    gradient, followed by an UNCONDITIONAL from-scratch gradient
+    ``full_grad(alpha)`` and a stopping test ``violation(alpha, g)`` on the
+    fresh gradient.
+
+    Two reasons over a single loop with a conditional refresh: (1) under
+    vmap (every divide-step caller) a batched-predicate ``lax.cond``
+    executes both branches, which would silently run the full recompute
+    every iteration; (2) the convergence test at a block boundary sees the
+    TRUE gradient, so f32 drift accumulated across the block's updates
+    cannot make the stopping test lie at tight tolerances.
+
+    ``step(alpha, g)`` returns the new iterate, its gradient and the
+    clamped violation of the iterate it improved.  ``trace`` is an optional
+    carry (``None`` has no leaves, so the untraced loop state is that of a
+    loop without it); when present it records one (pg_max=violation,
+    objective, n_free) sample per step at the pre-update iterate, with
+    ``pvec`` the linear term of the objective.  Returns
+    ``(alpha, grad, iters, pg_max, trace)`` with ``iters`` counting steps
+    and ``pg_max`` the last fresh-gradient violation.
+    """
+    def record(tr, alpha, g, viol):
+        return trace_record(tr, pg_max=viol,
+                            objective=objective(alpha, g, pvec),
+                            n_free=_n_free(alpha, cvec, mask))
+
+    def outer_cond(state):
+        _, _, it, viol, _ = state
+        return (viol > tol) & (it < max_iters)
+
+    def outer_body(state):
+        alpha, g, it, viol, tr = state
+        block = jnp.minimum(refresh_every, max_iters - it)
+
+        def inner_cond(st):
+            _, _, _, k, viol, _ = st
+            return (viol > tol) & (k < refresh_every) & (k < block)
+
+        def inner_body(st):
+            alpha, g, it, k, _, tr = st
+            alpha2, g2, viol = step(alpha, g)
+            tr = tr if tr is None else record(tr, alpha, g, viol)
+            return alpha2, g2, it + 1, k + 1, viol, tr
+
+        alpha, g, it, _, _, tr = lax.while_loop(
+            inner_cond, inner_body, (alpha, g, it, 0, viol, tr))
+        g = full_grad(alpha)
+        return alpha, g, it, jnp.maximum(violation(alpha, g), 0.0), tr
+
+    g = full_grad(alpha)
+    viol0 = jnp.maximum(violation(alpha, g), 0.0)
+    return lax.while_loop(outer_cond, outer_body, (alpha, g, 0, viol0, trace))
+
+
 def _pairwise_mvp_loop(alpha, cvec, avec, mask, gid, n_groups, qdiag, qij_fn,
                        rank2_fn, full_grad, tol, max_iters, refresh_every,
                        trace=None, pvec=None):
     """Shared pairwise maximal-violating-pair engine (dense and matvec
     front-ends differ only in how Q entries and the rank-2 gradient update
-    are produced).
-
-    Structure: an outer loop of refresh blocks, each an inner loop of up to
-    ``refresh_every`` rank-2 steps on the maintained gradient, followed by
-    an UNCONDITIONAL from-scratch gradient recompute and a stopping test on
-    the fresh gradient.  Two reasons over a single loop with a conditional
-    refresh: (1) under vmap (every divide-step caller) a batched-predicate
-    ``lax.cond`` executes both branches, which would silently run the full
-    recompute every iteration; (2) the convergence test at a block boundary
-    sees the TRUE gradient, so f32 drift accumulated across the block's
-    rank-2 updates cannot make the stopping test lie at tight tolerances.
-    Returns (alpha, grad, iters, pg_max) with ``iters`` counting pair steps
-    and ``pg_max`` the last fresh-gradient violation.  Pairs are drawn
-    within one group (``gid``/``n_groups``): the selected pair belongs to
-    the group with the widest multiplier-bracket violation, so every
-    group's constraint is preserved exactly and the stopping test is the
-    max gap over groups.
-
-    ``trace`` (static ``None`` gate) records one (pg_max=violation,
-    objective, n_free) sample per pair step; when enabled the loop returns
-    a 5-tuple with the trace appended.  ``pvec`` supplies the linear term
-    for the objective column and is only required when tracing.
+    are produced), run by ``_refresh_loop`` with one rank-2 step per
+    iteration.  ``iters`` counts pair steps.  Pairs are drawn within one
+    group (``gid``/``n_groups``): the selected pair belongs to the group
+    with the widest multiplier-bracket violation, so every group's
+    constraint is preserved exactly and the stopping test is the max gap
+    over groups.  Returns ``(alpha, grad, iters, pg_max, trace)``;
+    ``trace``/``pvec`` as in ``_refresh_loop``.
     """
     safe = _safe_a(avec)
     ingrp = gid[None, :] == jnp.arange(n_groups)[:, None]      # (G, n)
@@ -934,57 +916,9 @@ def _pairwise_mvp_loop(alpha, cvec, avec, mask, gid, n_groups, qdiag, qij_fn,
         g = rank2_fn(g, i, j, di, dj)
         return alpha, g, jnp.maximum(viol, 0.0)
 
-    def inner_cond(state):
-        _, _, _, k, viol = state
-        return (viol > tol) & (k < refresh_every)
-
-    def inner_body(state):
-        alpha, g, it, k, _ = state
-        alpha, g, viol = pair_step(alpha, g)
-        return alpha, g, it + 1, k + 1, viol
-
-    def outer_cond(state):
-        _, _, it, viol = state
-        return (viol > tol) & (it < max_iters)
-
-    def outer_body(state):
-        alpha, g, it, viol = state
-        block = jnp.minimum(refresh_every, max_iters - it)
-        alpha, g, it, _, _ = lax.while_loop(
-            lambda st: inner_cond(st) & (st[3] < block), inner_body,
-            (alpha, g, it, 0, viol))
-        g = full_grad(alpha)
-        _, _, viol = select(alpha, g)
-        return alpha, g, it, jnp.maximum(viol, 0.0)
-
-    g = full_grad(alpha)
-    _, _, viol0 = select(alpha, g)
-
-    if trace is None:
-        return lax.while_loop(outer_cond, outer_body,
-                              (alpha, g, 0, jnp.maximum(viol0, 0.0)))
-
-    def inner_body_t(state):
-        alpha, g, it, k, _, tr = state
-        alpha2, g2, viol = pair_step(alpha, g)
-        tr = trace_record(tr, pg_max=viol,
-                          objective=objective(alpha, g, pvec),
-                          n_free=_n_free(alpha, cvec, mask))
-        return alpha2, g2, it + 1, k + 1, viol, tr
-
-    def outer_body_t(state):
-        alpha, g, it, viol, tr = state
-        block = jnp.minimum(refresh_every, max_iters - it)
-        alpha, g, it, _, _, tr = lax.while_loop(
-            lambda st: (st[4] > tol) & (st[3] < block),
-            inner_body_t, (alpha, g, it, 0, viol, tr))
-        g = full_grad(alpha)
-        _, _, viol = select(alpha, g)
-        return alpha, g, it, jnp.maximum(viol, 0.0), tr
-
-    return lax.while_loop(
-        lambda st: (st[3] > tol) & (st[2] < max_iters), outer_body_t,
-        (alpha, g, 0, jnp.maximum(viol0, 0.0), trace))
+    return _refresh_loop(pair_step, lambda al, g: select(al, g)[2],
+                         full_grad, alpha, cvec, mask, pvec, tol, max_iters,
+                         refresh_every, trace)
 
 
 @partial(jax.jit, static_argnames=("max_iters", "refresh_every", "n_groups"))
@@ -1032,7 +966,7 @@ def solve_eq_qp(
     alpha = _project_box_equality_grouped(alpha, cvec, avec, dvec, gidv,
                                           n_groups, mask)
 
-    out = _pairwise_mvp_loop(
+    alpha, g, iters, pg_max, tr = _pairwise_mvp_loop(
         alpha, cvec, avec, mask, gidv, n_groups,
         qdiag=jnp.diagonal(Q),
         qij_fn=lambda i, j: Q[i, j],
@@ -1040,8 +974,6 @@ def solve_eq_qp(
         full_grad=lambda al: f32_matmul(Q, al) + pvec,
         tol=tol, max_iters=max_iters, refresh_every=refresh_every,
         trace=trace, pvec=pvec)
-    alpha, g, iters, pg_max = out[:4]
-    tr = out[4] if trace is not None else None
     alpha, g = _restore_equality_grouped(alpha, g, lambda k: Q[:, k], cvec,
                                          avec, dvec, gidv, n_groups, mask)
     return SolveResult(alpha, g, iters, pg_max, trace=tr)
@@ -1129,12 +1061,9 @@ def _blocked_mvp_loop(alpha, cvec, avec, mask, gid, n_groups, block, sweeps,
     sub-QP, their writes routed onto a valid slot so duplicate scatter
     writes are identical and therefore deterministic.
 
-    Same outer structure as ``_pairwise_mvp_loop``: refresh blocks of up to
-    ``refresh_every`` rank-2B iterations on the maintained gradient, then
-    an unconditional from-scratch recompute and a stopping test on the
-    fresh gradient (vmap-safe, drift-bounded).  ``iters`` counts outer
-    blocked iterations.  ``trace``/``pvec`` as in ``_pairwise_mvp_loop``
-    (one sample per rank-2B iteration; 5-tuple return when enabled).
+    Run by ``_refresh_loop`` like ``_pairwise_mvp_loop``, with one rank-2B
+    iteration per step; ``iters`` counts outer blocked iterations and the
+    return value is the same 5-tuple.
     """
     n = alpha.shape[0]
     safe = _safe_a(avec)
@@ -1184,54 +1113,9 @@ def _blocked_mvp_loop(alpha, cvec, avec, mask, gid, n_groups, block, sweeps,
         g = rank2b_fn(g, idx, delta)
         return alpha, g, jnp.maximum(viol, 0.0)
 
-    def inner_cond(state):
-        _, _, _, k, viol = state
-        return (viol > tol) & (k < refresh_every)
-
-    def inner_body(state):
-        alpha, g, it, k, _ = state
-        alpha, g, viol = block_step(alpha, g)
-        return alpha, g, it + 1, k + 1, viol
-
-    def outer_cond(state):
-        _, _, it, viol = state
-        return (viol > tol) & (it < max_iters)
-
-    def outer_body(state):
-        alpha, g, it, viol = state
-        blk = jnp.minimum(refresh_every, max_iters - it)
-        alpha, g, it, _, _ = lax.while_loop(
-            lambda st: inner_cond(st) & (st[3] < blk), inner_body,
-            (alpha, g, it, 0, viol))
-        g = full_grad(alpha)
-        return alpha, g, it, jnp.maximum(gap(*sides(alpha, g)), 0.0)
-
-    g = full_grad(alpha)
-    viol0 = jnp.maximum(gap(*sides(alpha, g)), 0.0)
-
-    if trace is None:
-        return lax.while_loop(outer_cond, outer_body, (alpha, g, 0, viol0))
-
-    def inner_body_t(state):
-        alpha, g, it, k, _, tr = state
-        alpha2, g2, viol = block_step(alpha, g)
-        tr = trace_record(tr, pg_max=viol,
-                          objective=objective(alpha, g, pvec),
-                          n_free=_n_free(alpha, cvec, mask))
-        return alpha2, g2, it + 1, k + 1, viol, tr
-
-    def outer_body_t(state):
-        alpha, g, it, viol, tr = state
-        blk = jnp.minimum(refresh_every, max_iters - it)
-        alpha, g, it, _, _, tr = lax.while_loop(
-            lambda st: (st[4] > tol) & (st[3] < blk), inner_body_t,
-            (alpha, g, it, 0, viol, tr))
-        g = full_grad(alpha)
-        return alpha, g, it, jnp.maximum(gap(*sides(alpha, g)), 0.0), tr
-
-    return lax.while_loop(
-        lambda st: (st[3] > tol) & (st[2] < max_iters), outer_body_t,
-        (alpha, g, 0, viol0, trace))
+    return _refresh_loop(block_step, lambda al, g: gap(*sides(al, g)),
+                         full_grad, alpha, cvec, mask, pvec, tol, max_iters,
+                         refresh_every, trace)
 
 
 @partial(jax.jit, static_argnames=("block", "sweeps", "max_iters",
@@ -1281,15 +1165,13 @@ def solve_eq_qp_block(
     alpha = _project_box_equality_grouped(alpha, cvec, avec, dvec, gidv,
                                           n_groups, mask)
 
-    out = _blocked_mvp_loop(
+    alpha, g, iters, pg_max, tr = _blocked_mvp_loop(
         alpha, cvec, avec, mask, gidv, n_groups, B, sweeps,
         qbb_fn=lambda idx: Q[idx][:, idx],
         rank2b_fn=lambda g, idx, delta: g + f32_matmul(Q[:, idx], delta),
         full_grad=lambda al: f32_matmul(Q, al) + pvec,
         tol=tol, max_iters=max_iters, refresh_every=refresh_every,
         trace=trace, pvec=pvec)
-    alpha, g, iters, pg_max = out[:4]
-    tr = out[4] if trace is not None else None
     alpha, g = _restore_equality_grouped(alpha, g, lambda k: Q[:, k], cvec,
                                          avec, dvec, gidv, n_groups, mask)
     return SolveResult(alpha, g, iters, pg_max, trace=tr)
@@ -1431,7 +1313,7 @@ def solve_eq_qp_matvec(
         def qbb_fn(idx):
             return op.qbb(idx).astype(acc)
 
-        out = _blocked_mvp_loop(
+        alpha, g, iters, pg_max, tr = _blocked_mvp_loop(
             alpha, cvec, avec, mask, gidv, n_groups, B, sweeps,
             qbb_fn=qbb_fn, rank2b_fn=rank2b_fn, full_grad=full_grad,
             tol=tol, max_iters=max_iters,
@@ -1444,14 +1326,12 @@ def solve_eq_qp_matvec(
         def rank2_fn(g, i, j, di, dj):
             return rank2b_fn(g, jnp.stack([i, j]), jnp.stack([di, dj]))
 
-        out = _pairwise_mvp_loop(
+        alpha, g, iters, pg_max, tr = _pairwise_mvp_loop(
             alpha, cvec, avec, mask, gidv, n_groups,
             qdiag=op.qdiag().astype(acc),
             qij_fn=qij_fn, rank2_fn=rank2_fn, full_grad=full_grad,
             tol=tol, max_iters=max_iters, refresh_every=refresh_every,
             trace=trace, pvec=pvec)
-    alpha, g, iters, pg_max = out[:4]
-    tr = out[4] if trace is not None else None
 
     def q_col(k):
         # XLA pairwise regardless of backend (one skinny column), under the

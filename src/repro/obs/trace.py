@@ -19,8 +19,9 @@ ring keeps the LAST ``cap`` samples and ``trace_fetch`` reports how many
 leading samples were dropped — the tail is where convergence curves live.
 
 Gating is by Python ``None`` (static), the same pattern as
-``compute_dtype=None``: with ``trace=None`` every solver builds exactly the
-pre-trace jaxpr, so default trajectories stay bit-identical.
+``compute_dtype=None``: the ring is an optional loop carry, and with
+``trace=None`` it has no leaves and no recording op enters the program, so
+default trajectories stay bit-identical.
 """
 from __future__ import annotations
 

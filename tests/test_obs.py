@@ -18,6 +18,7 @@ import json
 import math
 import threading
 import time
+from functools import partial
 
 import numpy as np
 import pytest
@@ -25,8 +26,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import Kernel
-from repro.core.solver import (solve_box_qp, solve_box_qp_matvec,
-                               solve_eq_qp, solve_with_shrinking)
+from repro.core.solver import (solve_box_qp, solve_box_qp_block,
+                               solve_box_qp_matvec, solve_eq_qp,
+                               solve_eq_qp_block, solve_eq_qp_matvec,
+                               solve_eq_qp_shrink, solve_with_shrinking)
 from repro.data import gaussian_mixture
 from repro.obs.metrics import Counter, Gauge, LatencyHistogram, MetricsRegistry
 from repro.obs.spans import SpanTracer, span
@@ -94,40 +97,74 @@ def test_trace_record_under_jit_and_vmap():
 # solver bit-identity: tracing observes, never steers
 # ---------------------------------------------------------------------------
 
-def test_traced_box_solve_is_bit_identical():
-    X, y = _problem()
-    Q = (y[:, None] * y[None, :]) * (KERN.pairwise(X, X))
-    r0 = solve_box_qp(Q, 2.0, tol=1e-5, max_iters=2000)
-    r1 = solve_box_qp(Q, 2.0, tol=1e-5, max_iters=2000, trace=trace_init(32))
-    assert np.array_equal(np.asarray(r0.alpha), np.asarray(r1.alpha))
-    assert int(r0.iters) == int(r1.iters)
+def _box(solver, seed, **kw):
+    X, y = _problem(seed=seed)
+    Q = (y[:, None] * y[None, :]) * KERN.pairwise(X, X)
+    return partial(solver, Q, 2.0, **kw)
+
+
+def _eq(solver, seed, **kw):
+    X, _ = _problem(seed=seed)
+    return partial(solver, KERN.pairwise(X, X), 1.0, 1.0, 0.3 * X.shape[0],
+                   tol=1e-4, **kw)
+
+
+def _matvec(**kw):
+    X, y = _problem(seed=5)
+    return partial(solve_box_qp_matvec, X, y, KERN, 2.0, tol=1e-4,
+                   max_iters=400, block=16, sweeps=2, **kw)
+
+
+def _eq_matvec(**kw):
+    X, _ = _problem(seed=6)
+    return partial(solve_eq_qp_matvec, X, jnp.ones(X.shape[0]), KERN, 1.0,
+                   1.0, 0.3 * X.shape[0], tol=1e-4, max_iters=600, **kw)
+
+
+# entry -> (builds the solve, whether its last sample is the returned
+# stopping value: the shrinking wrappers recompute it on the full problem
+# and the equality loops return the fresh-gradient violation instead)
+TRACED_SOLVES = {
+    "solve_box_qp": (lambda: _box(solve_box_qp, 0, tol=1e-5,
+                                  max_iters=2000), True),
+    "solve_box_qp_block": (lambda: _box(solve_box_qp_block, 7, tol=1e-5,
+                                        max_iters=2000, block=8), True),
+    "solve_with_shrinking": (lambda: _box(solve_with_shrinking, 1, tol=1e-4,
+                                          max_iters=4000, rounds=3), False),
+    "solve_box_qp_matvec-xla": (_matvec, True),
+    "solve_box_qp_matvec-cached": (lambda: _matvec(cache_cap=48), True),
+    "solve_box_qp_matvec-pallas": (lambda: _matvec(use_pallas=True), True),
+    "solve_eq_qp": (lambda: _eq(solve_eq_qp, 2, max_iters=4000), False),
+    "solve_eq_qp_block": (lambda: _eq(solve_eq_qp_block, 3, max_iters=2000,
+                                      block=4), False),
+    "solve_eq_qp_shrink": (lambda: _eq(solve_eq_qp_shrink, 4,
+                                       max_iters=4000, rounds=3), False),
+    "solve_eq_qp_matvec-b1": (_eq_matvec, False),
+    "solve_eq_qp_matvec-b4": (lambda: _eq_matvec(block=4), False),
+}
+
+
+@pytest.mark.parametrize("entry", list(TRACED_SOLVES))
+def test_traced_solve_is_bit_identical(entry):
+    """Tracing observes, never steers: every engine's traced solve returns
+    the untraced result bit for bit, with one sample per iteration."""
+    build, last_is_stop = TRACED_SOLVES[entry]
+    solve = build()
+    r0 = solve(trace=None)
+    r1 = solve(trace=trace_init(32))
+    for field in ("alpha", "grad", "iters", "pg_max"):
+        assert np.array_equal(np.asarray(getattr(r0, field)),
+                              np.asarray(getattr(r1, field))), field
+    assert r0.trace is None
     out = trace_fetch(r1.trace)
+    assert out["samples"] > 0
     assert out["samples"] + out["dropped"] == int(r1.iters)
     # the recorded columns carry real values
-    assert out["pg_max"][-1] == pytest.approx(float(r1.pg_max), rel=1e-6)
-    assert all(f == int(f) and 0 <= f <= Q.shape[0] for f in out["n_free"])
-
-
-def test_traced_shrinking_solve_is_bit_identical():
-    X, y = _problem(seed=1)
-    Q = (y[:, None] * y[None, :]) * (KERN.pairwise(X, X))
-    r0 = solve_with_shrinking(Q, 2.0, tol=1e-4, max_iters=4000, rounds=3)
-    r1 = solve_with_shrinking(Q, 2.0, tol=1e-4, max_iters=4000, rounds=3,
-                              trace=trace_init(64))
-    assert np.array_equal(np.asarray(r0.alpha), np.asarray(r1.alpha))
-    assert trace_fetch(r1.trace)["samples"] > 0
-
-
-def test_traced_eq_solve_is_bit_identical():
-    X, _ = _problem(seed=2)
-    n = X.shape[0]
-    Q = KERN.pairwise(X, X)
-    kw = dict(tol=1e-4, max_iters=4000)
-    r0 = solve_eq_qp(Q, 1.0, 1.0, 0.3 * n, **kw)
-    r1 = solve_eq_qp(Q, 1.0, 1.0, 0.3 * n, trace=trace_init(32), **kw)
-    assert np.array_equal(np.asarray(r0.alpha), np.asarray(r1.alpha))
-    out = trace_fetch(r1.trace)
-    assert out["samples"] > 0 and "pg_max" in out
+    n = r1.alpha.shape[0]
+    assert all(f == int(f) and 0 <= f <= n for f in out["n_free"])
+    if last_is_stop:
+        assert out["pg_max"][-1] == pytest.approx(float(r1.pg_max), rel=1e-6)
+    assert ("cache_hits" in out) == (r1.cache_hits is not None)
 
 
 def test_traced_matvec_solve_stays_host_sync_free():
