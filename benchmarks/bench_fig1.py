@@ -42,7 +42,7 @@ def run(n: int = 2000) -> list:
         pc = jnp.full_like(yc, -1.0)
         cc = jnp.full_like(yc, C)
         ac = jnp.where(mask, part.gather(jnp.zeros(Xtr.shape[0])), 0.0)[:, None, :]
-        ac = _solve_clusters(cfg, part.gather(Xtr), yc, pc, cc, ac, mask)
+        ac, _ = _solve_clusters(cfg, part.gather(Xtr), yc, pc, cc, ac, mask)
         a_rand = part.scatter(ac[:, 0, :], Xtr.shape[0])
         f_rand = float(0.5 * a_rand @ Q @ a_rand - a_rand.sum())
         bound_rand = theorem1_bound(kern, Xtr, jnp.asarray(rand_assign), C)

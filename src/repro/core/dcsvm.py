@@ -191,7 +191,7 @@ def _solve_clusters(
     aeq: Optional[Array] = None, geq: Optional[Array] = None,
     deq: Optional[Array] = None, n_groups: int = 1,
     Xcb: Optional[Array] = None, lbc: Optional[Array] = None,
-) -> Array:
+):
     """Solve the independent generalized sub-QPs of one level.
     Xc: (k, nc, d), mask: (k, nc); sc/pc/cc/ac are class-stacked
     (k, n_rows, nc) sign vectors, linear terms, per-coordinate boxes and
@@ -204,7 +204,8 @@ def _solve_clusters(
     ``_split_eq_targets`` — each sub-QP keeps its own hyperplane(s)
     ``a'u_c = d_c,g`` via the pairwise (``eq_block_size <= 1``) or rank-2B
     blocked engine (warm starts are projected feasible inside the
-    solver)."""
+    solver).  Returns the new (k, n_rows, nc) duals and each sub-QP's CD
+    iterations, (k, n_rows)."""
     k, nc, _ = Xc.shape
     n_cls = sc.shape[1]
     has_eq = aeq is not None
@@ -255,9 +256,9 @@ def _solve_clusters(
             else:
                 res = S.solve_box_qp(
                     Qi, ci, alpha0=ai, tol=cfg.tol, max_iters=cfg.max_iters,
-                    active_mask=mi, p=pi,
+                    active_mask=mi, p=pi, use_pallas=use_pallas,
                 )
-            return res.alpha
+            return res.alpha, res.iters
 
         return jax.vmap(per_class)(Si, Pi, Ci, Ai, *eq)      # (n_cls, nc)
 
@@ -265,9 +266,26 @@ def _solve_clusters(
         + ((Xcb, lbc) if dedup else ()) \
         + ((aeq, geq, deq) if has_eq else ())
     # sequential sweep bounds peak memory at one cluster's Grams
-    return _map_classes(one, args,
-                        gramop.fits_budget(k * n_cls * nc * nc,
-                                           cfg.gram_budget))
+    return _map_classes(one, args, _fits_together(cfg, k, n_cls, nc))
+
+
+def _fits_together(cfg: DCSVMConfig, k: int, n_rows: int, n: int) -> bool:
+    """Whether k * n_rows sub-QPs of size n (k clusters of a level, or the
+    refine's k=1) are solved together, vmapped: their stacked Grams fit the
+    budget.  Otherwise the k clusters (the refine's rows) are a sequential
+    sweep."""
+    return gramop.fits_budget(k * n_rows * n * n, cfg.gram_budget)
+
+
+def _greedy_cd(td: TaskDual, batch: int, block: bool,
+               use_pallas: bool) -> str:
+    """The single-coordinate CD engine (``"pallas"`` or ``"xla"``) of a
+    solve of ``batch`` sub-QPs run together; ``"xla"`` where the equality
+    or block engines run instead (``block``: the block rule picks block
+    CD)."""
+    if td.has_equality or block:
+        return "xla"
+    return S.greedy_cd_engine(batch, use_pallas)
 
 
 def _solve_subset(cfg: DCSVMConfig, td: TaskDual, alpha: Array, idx: Array,
@@ -282,8 +300,7 @@ def _solve_subset(cfg: DCSVMConfig, td: TaskDual, alpha: Array, idx: Array,
     Ks = gram(cfg.kernel, Xs, Xs, use_pallas=use_pallas,
               compute_dtype=cfg.compute_dtype)
     ss, ps, cs, as_ = td.S[:, idx], td.P[:, idx], td.Cvec[:, idx], alpha[:, idx]
-    fits = gramop.fits_budget(td.S.shape[0] * Xs.shape[0] ** 2,
-                              cfg.gram_budget)
+    fits = _fits_together(cfg, 1, td.S.shape[0], Xs.shape[0])
 
     if td.has_equality:
         # per-group sub-targets: the full targets minus the frozen
@@ -321,7 +338,8 @@ def _solve_subset(cfg: DCSVMConfig, td: TaskDual, alpha: Array, idx: Array,
             )
         else:
             res = S.solve_box_qp(Qs, ci, alpha0=ai, tol=cfg.tol,
-                                 max_iters=cfg.max_iters, p=pi)
+                                 max_iters=cfg.max_iters, p=pi,
+                                 use_pallas=use_pallas)
         return res.alpha
 
     new = _map_classes(per_class, (ss, ps, cs, as_), fits)
@@ -409,7 +427,7 @@ def _solve_full(cfg: DCSVMConfig, td: TaskDual, alpha: Array,
             return S.solve_with_shrinking(
                 Q, ci, alpha0=ai, tol=cfg.tol, max_iters=cfg.max_iters,
                 rounds=cfg.shrink_rounds, block=cfg.block, p=pi,
-                trace=_tr(),
+                trace=_tr(), use_pallas=use_pallas,
             )
 
         return _map_classes(per_class, (td.S, td.P, td.Cvec, alpha),
@@ -563,11 +581,15 @@ def _fit_algorithm1(
                 geqc = jnp.moveaxis(dpart.gather(td.group_ids.T), -1, 1)
                 deqc = _split_eq_targets(aeqc, cc, mask, geqc,
                                          jnp.asarray(td.Deq), td.n_groups)
-        with span(f"divide/level{l}/solve"):
-            ac = _solve_clusters(cfg, Xc, sc, pc, cc, ac, mask,
-                                 use_pallas=use_pallas, aeq=aeqc, geq=geqc,
-                                 deq=deqc, n_groups=max(td.n_groups, 1),
-                                 Xcb=Xcb, lbc=lbc)
+        nc, n_cls = dpart.nc, td.S.shape[0]
+        cd = _greedy_cd(td, n_cls * (
+            kl if _fits_together(cfg, kl, n_cls, nc) else 1),
+            0 < cfg.block < nc, use_pallas)
+        with span(f"divide/level{l}/solve", cd=cd):
+            ac, iters = _solve_clusters(
+                cfg, Xc, sc, pc, cc, ac, mask, use_pallas=use_pallas,
+                aeq=aeqc, geq=geqc, deq=deqc, n_groups=max(td.n_groups, 1),
+                Xcb=Xcb, lbc=lbc)
             alpha = dpart.scatter(jnp.moveaxis(ac, 1, -1), nd).T
             alpha.block_until_ready()
         t_train = time.perf_counter() - t0
@@ -577,7 +599,8 @@ def _fit_algorithm1(
             sv_base = np.unique(base_index[sv_idx])
         st = dict(level=l, clusters=kl, cluster_time=t_cluster, train_time=t_train,
                   n_sv=int(len(sv_base)),
-                  balance_redirected=partition.redirected)
+                  balance_redirected=partition.redirected,
+                  iters=int(np.sum(np.asarray(iters))), cd=cd)
         stats.append(st)
         if callback is not None:
             callback(l, alpha, st)
@@ -587,7 +610,10 @@ def _fit_algorithm1(
     # ---- level 0: refine + full solve -----------------------------------
     t0 = time.perf_counter()
     if cfg.refine and sv_idx is not None and 0 < len(sv_idx) < nd:
-        with span("conquer/refine"):
+        ns, n_cls = len(sv_idx), td.S.shape[0]
+        cd = _greedy_cd(td, n_cls if _fits_together(cfg, 1, n_cls, ns)
+                        else 1, cfg.block > 0, use_pallas)
+        with span("conquer/refine", cd=cd):
             alpha = _solve_subset(cfg, td, alpha, jnp.asarray(sv_idx),
                                   use_pallas=use_pallas)
     subqp = _level0_subqp(cfg, td, use_pallas)

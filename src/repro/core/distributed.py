@@ -148,7 +148,7 @@ def divide_step(
             else:
                 res = S.solve_box_qp(Qi, ci, alpha0=ai, tol=tol,
                                      max_iters=max_iters, active_mask=mi,
-                                     p=pi)
+                                     p=pi, use_pallas=use_pallas)
             return res.alpha
 
         if resident:

@@ -64,7 +64,7 @@ criterion) for the equality family.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+from functools import lru_cache, partial
 from typing import NamedTuple, Optional
 
 import jax
@@ -72,7 +72,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core import colcache, gramop
-from repro.core.kernels import HIGHEST, Kernel, f32_matmul
+from repro.core.kernels import (HIGHEST, Kernel, f32_matmul,
+                                resolve_use_pallas)
 from repro.obs.trace import ConvTrace, trace_record
 
 Array = jax.Array
@@ -205,7 +206,83 @@ def _masked_record(mask, cvec, pvec):
 # Greedy single-coordinate CD (paper-faithful conquer/sub-solver)
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("max_iters",))
+def _greedy_step(Q, cvec, diag, mask):
+    """One greedy CD step on a dense ``Q``: ``step(alpha, g)`` returns the
+    new ``(alpha, g)`` and max |pg| of the iterate it improved."""
+    def step(alpha, g):
+        pg = jnp.where(mask, proj_grad(alpha, g, cvec), 0.0)
+        i = jnp.argmax(jnp.abs(pg))
+        new_ai = jnp.clip(alpha[i] - g[i] / diag[i], 0.0, cvec[i])
+        delta = new_ai - alpha[i]
+        # stopping value computed from the *pre-update* pg (cheap, standard)
+        return alpha.at[i].set(new_ai), g + delta * Q[:, i], jnp.max(jnp.abs(pg))
+    return step
+
+
+def _masked_pg_max(alpha, g, cvec, mask):
+    return jnp.max(jnp.abs(jnp.where(mask, proj_grad(alpha, g, cvec), 0.0)))
+
+
+def _greedy_cd_xla(Q, alpha, g, cvec, diag, mask, tol, max_iters):
+    """The XLA loop of ``solve_box_qp`` without a trace:
+    ``(alpha, g, iters, pg_max)``."""
+    (alpha, g), iters, pg_max, _ = _cd_loop(
+        _greedy_step(Q, cvec, diag, mask), None, (alpha, g),
+        _masked_pg_max(alpha, g, cvec, mask), tol, max_iters, None)
+    return alpha, g, iters, pg_max
+
+
+# The largest batch the kernel solves.  It takes the problems of a batch one
+# after another, so its time grows with the batch; the vmapped XLA loop
+# steps them in lockstep, at a trip cost that grows more slowly.  On one
+# v5e the kernel won at a batch of 16 (1,563 points a problem) and lost at
+# 64 and 256 (98-782 points); the problem size did not move the crossover.
+KERNEL_MAX_BATCH = 16
+
+
+def greedy_cd_kernel_wins(batch: int) -> bool:
+    """Whether ``batch`` greedy solves run together (vmapped) are faster as
+    the ``greedy_cd`` kernel than as the lockstep XLA loop."""
+    return batch <= KERNEL_MAX_BATCH
+
+
+def greedy_cd_engine(batch: int, use_pallas) -> str:
+    """The engine an untraced f32 ``solve_box_qp`` runs for ``batch``
+    problems solved together (vmapped): ``"pallas"`` (the ``greedy_cd``
+    kernel) or ``"xla"`` (the while-loop)."""
+    kernel = resolve_use_pallas(use_pallas) and greedy_cd_kernel_wins(batch)
+    return "pallas" if kernel else "xla"
+
+
+@lru_cache(maxsize=None)
+def _greedy_cd_batched(max_iters: int):
+    """Greedy CD over a leading batch axis of (b, n, n) ``Q`` and (b, n)
+    vectors, with ``tol`` (b,).  Under ``jax.vmap`` the mapped axes merge
+    into that one batch, so the engine is chosen from the whole batch that
+    runs together."""
+    @jax.custom_batching.custom_vmap
+    def run(Q, alpha, g, cvec, diag, mask, tol):
+        if not greedy_cd_kernel_wins(alpha.shape[0]):
+            return jax.vmap(partial(_greedy_cd_xla, max_iters=max_iters))(
+                Q, alpha, g, cvec, diag, mask, tol)
+        from repro.kernels import ops as kops
+        # the kernel reads Q's column i as row i of Q.T (Q need not be
+        # bitwise symmetric)
+        return kops.greedy_cd(jnp.swapaxes(Q, 1, 2), alpha, g, cvec, diag,
+                              mask, tol, max_iters=max_iters)
+
+    @run.def_vmap
+    def _(axis_size, in_batched, *args):
+        args = [x if bat else jnp.broadcast_to(x, (axis_size,) + x.shape)
+                for x, bat in zip(args, in_batched)]
+        out = run(*(x.reshape((-1,) + x.shape[2:]) for x in args))
+        return (tuple(o.reshape((axis_size, -1) + o.shape[1:]) for o in out),
+                (True,) * len(out))
+
+    return run
+
+
+@partial(jax.jit, static_argnames=("max_iters", "use_pallas"))
 def solve_box_qp(
     Q: Array,
     C,
@@ -215,6 +292,7 @@ def solve_box_qp(
     active_mask: Optional[Array] = None,
     p=-1.0,
     trace: Optional[ConvTrace] = None,
+    use_pallas: Optional[bool] = None,
 ) -> SolveResult:
     """Greedy coordinate descent on a dense Q. vmap over leading dims is fine.
 
@@ -227,6 +305,12 @@ def solve_box_qp(
     ``trace`` (static gate, ``None`` records nothing) records one
     (pg_max, objective, n_free) sample per iteration into the ring buffer,
     evaluated at the pre-update iterate like the stopping value.
+
+    Untraced f32 solves on the Pallas backend (``use_pallas``, ``None`` =
+    auto) run the whole loop as the ``greedy_cd`` kernel, with the same
+    steps in the same order, wherever ``greedy_cd_kernel_wins`` says so for
+    the batch that runs together; otherwise each step is a trip of an XLA
+    ``while_loop``.
     """
     n = Q.shape[0]
     diag = jnp.maximum(jnp.diagonal(Q), 1e-12)
@@ -236,19 +320,17 @@ def solve_box_qp(
     g = f32_matmul(Q, alpha) + pvec
     mask = jnp.ones(n, bool) if active_mask is None else active_mask
 
-    def step(alpha, g):
-        pg = jnp.where(mask, proj_grad(alpha, g, cvec), 0.0)
-        i = jnp.argmax(jnp.abs(pg))
-        new_ai = jnp.clip(alpha[i] - g[i] / diag[i], 0.0, cvec[i])
-        delta = new_ai - alpha[i]
-        # stopping value computed from the *pre-update* pg (cheap, standard)
-        return alpha.at[i].set(new_ai), g + delta * Q[:, i], jnp.max(jnp.abs(pg))
-
+    if (trace is None and resolve_use_pallas(use_pallas)
+            and all(a.dtype == jnp.float32 for a in (Q, alpha, g))):
+        out = _greedy_cd_batched(max_iters)(
+            Q[None], alpha[None], g[None], cvec[None], diag[None], mask[None],
+            jnp.full((1,), tol, Q.dtype))
+        return SolveResult(*(o[0] for o in out))
     # one priming evaluation so the loop can exit immediately at the optimum
-    pg0 = jnp.max(jnp.abs(jnp.where(mask, proj_grad(alpha, g, cvec), 0.0)))
+    pg0 = _masked_pg_max(alpha, g, cvec, mask)
     (alpha, g), iters, pg_max, tr = _cd_loop(
-        step, _masked_record(mask, cvec, pvec), (alpha, g), pg0, tol,
-        max_iters, trace)
+        _greedy_step(Q, cvec, diag, mask), _masked_record(mask, cvec, pvec),
+        (alpha, g), pg0, tol, max_iters, trace)
     return SolveResult(alpha, g, iters, pg_max, trace=tr)
 
 
@@ -515,6 +597,7 @@ def solve_with_shrinking(
     block: int = 0,
     p=-1.0,
     trace: Optional[ConvTrace] = None,
+    use_pallas: Optional[bool] = None,
 ) -> SolveResult:
     """Outer shrinking rounds around the CD solver.
 
@@ -522,7 +605,8 @@ def solve_with_shrinking(
     bound with |g| > shrink_margin * tol are removed from the active set for
     the next round; the final round always re-activates everything so the
     returned KKT residual is on the FULL problem (LIBSVM's un-shrink check).
-    ``C``/``p`` may be per-coordinate vectors (generalized dual).
+    ``C``/``p`` may be per-coordinate vectors (generalized dual);
+    ``use_pallas`` is ``solve_box_qp``'s backend.
 
     ``pg_max`` is recomputed at the returned alpha (one Q @ alpha matvec):
     the inner solvers report the stopping value from the last *pre-update*
@@ -534,7 +618,8 @@ def solve_with_shrinking(
     alpha = jnp.zeros(n, Q.dtype) if alpha0 is None else alpha0
     cvec = _broadcast(C, n, Q.dtype)
     mask = jnp.ones(n, bool)
-    solver = solve_box_qp if block <= 0 else partial(solve_box_qp_block, block=block)
+    solver = (partial(solve_box_qp, use_pallas=use_pallas) if block <= 0
+              else partial(solve_box_qp_block, block=block))
     res = None
     # iteration counts accumulate on device; converting per round would force
     # a host sync between rounds and serialize dispatch

@@ -9,6 +9,8 @@
 #                    device-resident column cache lives in core.colcache)
 #   small_qp.py      the conquer step's B×B sub-QP coordinate-descent sweep,
 #                    all sweeps * B steps in one kernel with Qbb in VMEM
+#   greedy_cd.py     the cluster solves' greedy single-coordinate CD, a whole
+#                    solve in one kernel (Q.T in VMEM or one row DMA a step)
 # ops.py exposes jit'd wrappers (interpret mode on CPU, compiled on TPU);
 # ref.py holds the pure-jnp oracles the tests compare against.
 from repro.kernels import ops, ref

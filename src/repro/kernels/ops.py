@@ -16,6 +16,7 @@ from repro.kernels import kermat as _kermat
 from repro.kernels import kermatvec as _kermatvec
 from repro.kernels import kmeans_assign as _assign
 from repro.kernels import cd_update as _cd
+from repro.kernels import greedy_cd as _greedy
 from repro.kernels import small_qp as _small_qp
 
 
@@ -138,3 +139,15 @@ def small_qp_sweep(QbbT: jax.Array, gb: jax.Array, ab: jax.Array,
     ``diag`` (the guarded diagonal of ``Qbb``) are (B,) vectors."""
     return _small_qp.small_qp_sweep(QbbT, gb, ab, cb, diag, sweeps=sweeps,
                                     interpret=_interpret())
+
+
+def greedy_cd(QT: jax.Array, alpha: jax.Array, g: jax.Array, c: jax.Array,
+              d: jax.Array, mask: jax.Array, tol: jax.Array, *,
+              max_iters: int):
+    """Greedy single-coordinate CD on a batch of dense box QPs, each whole
+    solve in one Pallas kernel.  ``QT`` is (b, n, n), each problem's
+    ``Q.T``; ``alpha``, ``g``, ``c``, ``d`` (the guarded diagonal of Q) and
+    ``mask`` are (b, n), ``tol`` (b,).  Returns ``(alpha, g, iters,
+    pg_max)``."""
+    return _greedy.greedy_cd(QT, alpha, g, c, d, mask, tol,
+                             max_iters=max_iters, interpret=_interpret())

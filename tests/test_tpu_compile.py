@@ -2,9 +2,10 @@
 
 The TPU compiler is installed even where no chip is attached; these tests
 lower the main path's Pallas kernels at the covtype (d=54) and webspam
-(d=254) widths and the conquer's sub-QP sweep kernel, plus one level-1
-cluster solve at the paper deployment's cluster size, on one chip and
-sharded over the four of a ``v5e:2x2``.
+(d=254) widths, the conquer's sub-QP sweep kernel, the cluster solves'
+greedy CD kernel in both of its placements, plus one level-1 cluster solve
+at the paper deployment's cluster size, on one chip and sharded over the
+four of a ``v5e:2x2``.
 They catch what interpret mode cannot: a kernel Mosaic refuses (tiling,
 VMEM, an unsupported precision), a kernel inside ``shard_map`` without
 varying-axes types, an XLA replacement of a kernel, or a program that
@@ -95,6 +96,25 @@ def test_small_qp_sweep_compiles_for_v5e(one_chip, compiled_kernels, B):
     hlo, total = _compile(
         lambda QT, g, a, c, d: ops.small_qp_sweep(QT, g, a, c, d, sweeps=4),
         *args)
+    assert "tpu_custom_call" in hlo
+    assert total < HBM_BYTES
+
+
+@pytest.mark.parametrize("b,n", [(1, 12_500), (1, 3_125), (16, 1_563)])
+def test_greedy_cd_compiles_for_v5e(one_chip, compiled_kernels, b, n):
+    """The cluster solves' greedy CD kernel at the covtype cell's level-1
+    cluster (Q.T in HBM, one row DMA a step), its level-2 cluster (the
+    largest Q.T held in VMEM, 51 MB) and a batch of webspam's level-2
+    clusters (Q.T in VMEM): its scalar reductions, dynamic row reads, DMAs
+    and VMEM limit must pass Mosaic, which interpret mode does not check."""
+    def spec(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    hlo, total = _compile(
+        lambda QT, a, g, c, d, m, tol: ops.greedy_cd(QT, a, g, c, d, m, tol,
+                                                     max_iters=30_000),
+        spec(b, n, n), spec(b, n), spec(b, n), spec(b, n), spec(b, n),
+        spec(b, n, dtype=jnp.bool_), spec(b))
     assert "tpu_custom_call" in hlo
     assert total < HBM_BYTES
 
